@@ -1620,8 +1620,6 @@ def bench_paged_attention(quick=False):
 
 
 _SHARDING_SCRIPT = """
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import json
 import numpy as np
 import jax
@@ -1663,12 +1661,18 @@ def bench_programmed_sharding(arch="qwen2-0.5b"):
     multi-device mesh (2 data x 4 model).  Shape metadata only
     (eval_shape + shard_shape — no arrays are materialised); runs in a
     subprocess so the forced 8-device host platform never leaks into the
-    timing benchmarks of this process.  Returns the
+    timing benchmarks of this process.  The child is pinned to the CPU
+    backend: this process may already hold the accelerator, and a second
+    process that asks for it fails or hangs.  Returns the
     ``programmed_sharding`` section of ``BENCH_dpe.json``."""
     import os
     import subprocess
 
-    env = dict(os.environ)
+    env = dict(
+        os.environ,
+        JAX_PLATFORMS="cpu",
+        XLA_FLAGS="--xla_force_host_platform_device_count=8",
+    )
     proc = subprocess.run(
         [sys.executable, "-c", _SHARDING_SCRIPT % {"arch": arch}],
         capture_output=True,

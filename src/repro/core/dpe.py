@@ -30,6 +30,7 @@ circuit / auto) is resolved by :func:`resolve_backend`.
 """
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple
 
 import jax
@@ -231,9 +232,13 @@ def _faithful_matmul(
     # and the (Sx, M, Sw, nn, bn) partial stack stays cache-resident.
     for kb in range(nk):
         # One batched GEMM over the stacked slice-pair axis, in the
-        # transpose-free dot_general layout (Sx, M, Sw, Np).
+        # transpose-free dot_general layout (Sx, M, Sw, Np).  HIGHEST:
+        # a TPU's default f32 contraction is one bf16 pass, which would
+        # round the programmed (noisy) slice values to 8 mantissa bits
+        # before the ADC sees the partial sums.
         p = lax.dot_general(
             xsb[:, :, kb], wsb[:, kb], (((2,), (1,)), ((), ())),
+            precision=lax.Precision.HIGHEST,
             preferred_element_type=jnp.float32,
         ).reshape(sxn, m, swn, nn, bn)
         if cfg.adc_mode == "dynamic":
@@ -542,13 +547,17 @@ def dpe_matmul_prepared(
         # fused kernel: prepare_input (quantise + slice + DAC) runs
         # IN-kernel on the raw activations — the (Sx, M, Kp) slice
         # stack never touches HBM on the serve hot path
+        from repro.distributed.sharding import replicated_call
         from repro.kernels import ops as _kops
 
-        y = _kops.fused_sliced_matmul(
+        y = replicated_call(
+            functools.partial(
+                _kops.fused_sliced_matmul,
+                input_spec=cfg.input_spec, weight_spec=cfg.weight_spec,
+                array_size=cfg.array_size, rdac=cfg.rdac, radc=cfg.radc,
+                adc_mode=cfg.adc_mode,
+            ),
             xm.astype(jnp.float32), pw.slices, pw.scale,
-            input_spec=cfg.input_spec, weight_spec=cfg.weight_spec,
-            array_size=cfg.array_size, rdac=cfg.rdac, radc=cfg.radc,
-            adc_mode=cfg.adc_mode,
         )
         return y[:, :n].reshape(*lead, n)
     xs, sx = prepare_input(xm, cfg)

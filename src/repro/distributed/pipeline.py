@@ -18,8 +18,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from .sharding import pvary, shard_map
-
 __all__ = ["pipeline_apply"]
 
 
@@ -67,9 +65,9 @@ def pipeline_apply(
 
         # mark the carries as device-varying over the stage axis (VMA
         # typing: they become varying after the first ppermute)
-        buf0 = pvary(jnp.zeros_like(xs[0]), (axis,))
-        outs0 = pvary(
-            jnp.zeros((m,) + xs.shape[1:], xs.dtype), (axis,)
+        buf0 = lax.pcast(jnp.zeros_like(xs[0]), (axis,), to="varying")
+        outs0 = lax.pcast(
+            jnp.zeros((m,) + xs.shape[1:], xs.dtype), (axis,), to="varying"
         )
         (_, outs), _ = lax.scan(tick, (buf0, outs0), jnp.arange(n_ticks))
         # broadcast final outputs from the last stage to all stages
@@ -80,7 +78,7 @@ def pipeline_apply(
         )
         return outs
 
-    fn = shard_map(
+    fn = jax.shard_map(
         per_stage,
         mesh=mesh,
         in_specs=(P(axis), P()),

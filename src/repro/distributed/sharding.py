@@ -28,22 +28,8 @@ __all__ = [
     "logical_sharding",
     "param_sharding_rules",
     "programmed_sharding_rules",
-    "shard_map",
-    "pvary",
+    "replicated_call",
 ]
-
-# --- JAX-version compat -----------------------------------------------------
-# ``jax.shard_map`` was promoted out of jax.experimental after 0.4.x, and
-# ``jax.lax.pvary`` (varying-manual-axes typing for shard_map carries) only
-# exists on newer releases where shard_map enforces VMA typing.  On older
-# versions the collectives accept replicated carries directly, so pvary can
-# degrade to the identity.
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:  # pragma: no cover - depends on installed jax
-    from jax.experimental.shard_map import shard_map  # noqa: F401
-
-pvary = getattr(jax.lax, "pvary", lambda x, axes: x)
 
 # logical axis -> mesh axis (or tuple of mesh axes)
 LOGICAL_RULES: dict[str, object] = {
@@ -103,6 +89,20 @@ def rules_context(mesh: Mesh, rules: dict | None = None):
         yield
     finally:
         st.mesh, st.rules = prev
+
+
+def replicated_call(fn, *args):
+    """``fn(*args)``, run as one replicated program per device of the
+    active mesh (a plain call without one).  For Pallas kernels: Mosaic
+    kernels cannot be partitioned automatically, so under a mesh the
+    sharded operands are gathered and every device runs the whole
+    kernel."""
+    mesh = _ctx().mesh
+    if mesh is None:
+        return fn(*args)
+    return jax.shard_map(
+        fn, mesh=mesh, in_specs=P(), out_specs=P(), check_vma=False
+    )(*args)
 
 
 def _mesh_axes(logical: str, mesh: Mesh, rules: dict):

@@ -22,8 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import tpu_compiler_params
-
 __all__ = ["flash_attention_pallas"]
 
 _NEG = -1e30
@@ -141,8 +139,8 @@ def flash_attention_pallas(
             pltpu.VMEM((bq,), jnp.float32),
             pltpu.VMEM((bq, dh), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
-            ("parallel", "parallel", "arbitrary")
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
     )(q, k, v)

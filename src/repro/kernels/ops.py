@@ -10,7 +10,8 @@ check:
 * :func:`set_interpret` / env ``REPRO_KERNEL_INTERPRET`` — force the
   kernels to run (``True`` = interpret mode, the CI configuration;
   ``False`` = compiled, TPU only; ``None`` = auto: interpret iff not on
-  TPU).
+  TPU).  Forcing interpret mode on a TPU is an error, not a silent
+  slowdown of the serve path.
 * :func:`kernels_enabled` — should ``auto`` backends pick the Pallas
   kernels at all?  True on real TPU hardware, and under a forced
   ``set_interpret(True)`` (differential tests / kernel CI legs, where
@@ -90,13 +91,21 @@ def kernel_interpret(override: bool | None = None) -> bool:
     """Resolve the interpret flag for one kernel call.
 
     Per-call ``override`` beats the global/env override beats auto
-    (interpret iff the default backend is not a TPU)."""
+    (interpret iff the default backend is not a TPU).  A global/env
+    override that forces interpret mode on a TPU raises."""
     if override is not None:
         return override
+    on_tpu = jax.default_backend() == "tpu"
     forced = _interpret_override()
-    if forced is not None:
-        return forced
-    return jax.default_backend() != "tpu"
+    if forced is None:
+        return not on_tpu
+    if forced and on_tpu:
+        raise RuntimeError(
+            "kernel interpret mode is forced (set_interpret(True) or "
+            "REPRO_KERNEL_INTERPRET) on a TPU backend: the serving kernels "
+            "would run in the Pallas interpreter instead of Mosaic"
+        )
+    return forced
 
 
 def kernels_enabled() -> bool:

@@ -13,9 +13,12 @@ O(prefix), not O(max_len).
 
 Numerics contract (tests/test_paged_attention.py): the kernels are
 BITWISE equal to the dense-gather path in interpret mode.  Mapped
-blocks are copied into a full-S VMEM scratch (unmapped tail left zero),
-and the final grid step replays the exact jnp expression sequence of
-``attention_decode`` / ``attention_dense`` on that scratch.  Tail and
+blocks are copied into a full-S ``(KV, S, hd)`` VMEM scratch (unmapped
+tail left zero), and the final grid step replays the math of
+``attention_decode`` / ``attention_dense`` on that scratch as dots
+batched over KV heads, accumulated in f32 (DESIGN.md §7).  Compiled by
+Mosaic, the class against the XLA oracle is measured on the chip
+(DESIGN.md §7 table, ``chip_smoke.py``).  Tail and
 trash positions hold zeros here vs. junk in the gathered view, but both
 are masked to -1e30 before the softmax, ``exp`` underflows to exactly
 0.0, and a 0.0 probability contributes exactly 0.0 to the PV
@@ -31,14 +34,65 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from .compat import tpu_compiler_params
 
 __all__ = ["paged_decode_attention", "paged_chunk_attention"]
 
 _NEG = -1e30  # models.attention._NEG
+_CHUNK_VMEM_LIMIT = 64 * 1024 * 1024
+
+
+def _copy_block(kb_ref, vb_ref, ks_ref, vs_ref, kb, bs):
+    """Copy one mapped (bs, KV, hd) pool block into the (KV, S, hd)
+    scratch, one KV head at a time: each head's rows land as a dense
+    (bs, hd) slab, so no sublane padding of the KV axis and no in-kernel
+    reshape (Mosaic tiles only the last two dims)."""
+    off = pl.multiple_of(kb * bs, bs)
+    for kh in range(ks_ref.shape[0]):
+        ks_ref[kh, pl.ds(off, bs), :] = kb_ref[0, :, kh, :]
+        vs_ref[kh, pl.ds(off, bs), :] = vb_ref[0, :, kh, :]
+
+
+def _scores(q, k, interpret):
+    """(KV, R, dh) x (KV, S, dh) -> (KV, R, S) f32: one dot batched over
+    KV heads — the oracle's (b, k)-batched contraction, per slot.
+
+    Mosaic contracts the minor dim of ``k`` natively.  XLA:CPU (interpret
+    mode) picks a different GEMM kernel for a minor-contracting operand
+    than for the oracle's (batch, contract, free) copy of the gathered
+    cache, so interpret mode materialises that layout — pinned, or the
+    simplifier folds the transpose back into the dot — to replay the
+    oracle's summation order bitwise."""
+    prec = _precision(k.dtype)
+    if interpret:
+        kt = lax.optimization_barrier(jnp.swapaxes(k, 1, 2))
+        return jnp.einsum(
+            "krd,kds->krs", q, kt, precision=prec,
+            preferred_element_type=jnp.float32,
+        )
+    return jnp.einsum(
+        "krd,ksd->krs", q, k, precision=prec,
+        preferred_element_type=jnp.float32,
+    )
+
+
+def _pv(p, v):
+    """(KV, R, S) x (KV, S, dh) -> (KV, R, dh), accumulated in f32 and
+    rounded once to the cache dtype — what the oracle's cache-dtype
+    contraction computes (and the only accumulator Mosaic's matmul
+    accepts)."""
+    return jnp.einsum(
+        "krs,ksd->krd", p.astype(v.dtype), v, precision=_precision(v.dtype),
+        preferred_element_type=jnp.float32,
+    ).astype(v.dtype)
+
+
+def _precision(dtype):
+    """models.attention._precision: full f32 contraction for an f32 cache
+    (a TPU's default f32 matmul is one bf16 pass); bf16 is exact in one."""
+    return lax.Precision.HIGHEST if dtype == jnp.float32 else None
 
 
 def _decode_kernel(
@@ -46,18 +100,19 @@ def _decode_kernel(
     bt_ref,  # (B, nb) int32 block tables
     pos_ref,  # (B,) int32 current token index per slot
     # inputs
-    q_ref,  # (1, H, dh) this slot's query
+    q_ref,  # (1, KV, G, dh) this slot's query, grouped by KV head
     kb_ref,  # (1, bs, KV, hd) the mapped K pool block for this step
     vb_ref,  # (1, bs, KV, hd)
     # outputs
-    out_ref,  # (1, H, dh) pool dtype (attention_decode returns v.dtype)
+    out_ref,  # (1, KV, G, dh) pool dtype (attention_decode returns v.dtype)
     # scratch
-    ks_ref,  # (S, KV, hd) pool dtype — full logical K view
-    vs_ref,  # (S, KV, hd)
+    ks_ref,  # (KV, S, hd) pool dtype — full logical K view per KV head
+    vs_ref,  # (KV, S, hd)
     *,
     bs: int,
     nb: int,
     window: int,
+    interpret: bool,
 ):
     b = pl.program_id(0)
     kb = pl.program_id(1)
@@ -71,29 +126,16 @@ def _decode_kernel(
 
     @pl.when(kb <= lim)
     def _copy():
-        ks_ref[pl.ds(kb * bs, bs)] = kb_ref[0]
-        vs_ref[pl.ds(kb * bs, bs)] = vb_ref[0]
+        _copy_block(kb_ref, vb_ref, ks_ref, vs_ref, kb, bs)
 
     @pl.when(kb == nb - 1)
     def _attend():
-        # exact replica of attention_decode on the (S, KV, hd) scratch
-        h, dh = q_ref.shape[1], q_ref.shape[2]
-        kvh = ks_ref.shape[1]
-        g = h // kvh
+        # exact replica of attention_decode on the (KV, S, hd) scratch
+        dh = q_ref.shape[-1]
         scale = dh**-0.5
         k = ks_ref[...]
-        v = vs_ref[...]
-        qg = q_ref[0].reshape(kvh, g, dh)
-        s = (
-            jnp.einsum(
-                "kgd,skd->kgs",
-                qg.astype(k.dtype),
-                k,
-                preferred_element_type=jnp.float32,
-            )
-            * scale
-        )
-        ki = jnp.arange(nb * bs)[None, None, :]
+        s = _scores(q_ref[0].astype(k.dtype), k, interpret) * scale
+        ki = lax.broadcasted_iota(jnp.int32, s.shape, 2)
         mask = ki <= pos
         if window > 0:
             mask &= ki > pos - window
@@ -101,8 +143,12 @@ def _decode_kernel(
         m = jnp.max(s, axis=-1, keepdims=True)
         p = jnp.exp(s - m)
         l = jnp.sum(p, axis=-1, keepdims=True)
-        o = jnp.einsum("kgs,skd->kgd", (p / l).astype(v.dtype), v)
-        out_ref[0] = o.reshape(h, dh)
+        out_ref[0] = _pv(p / l, vs_ref[...])
+
+
+def _scratch(pool, nb):
+    kvh, hd = pool.shape[2:]
+    return pltpu.VMEM((kvh, nb * pool.shape[1], hd), pool.dtype)
 
 
 @functools.partial(
@@ -122,9 +168,11 @@ def paged_decode_attention(
     gathered logical view, reading only the mapped prefix blocks."""
     bsz, nb = pool_k.shape[1], block_tables.shape[1]
     b, h, dh = q.shape
+    kvh = pool_k.shape[2]
+    qg = q.reshape(b, kvh, h // kvh, dh)
 
     def q_map(i, kb, bt, p):
-        return (i, 0, 0)
+        return (i, 0, 0, 0)
 
     def kv_map(i, kb, bt, p):
         # clamp beyond-limit steps to the last mapped block: the index
@@ -133,29 +181,30 @@ def paged_decode_attention(
         return (bt[i, jnp.minimum(kb, p[i] // bsz)], 0, 0, 0)
 
     kernel = functools.partial(
-        _decode_kernel, bs=bsz, nb=nb, window=window
+        _decode_kernel, bs=bsz, nb=nb, window=window, interpret=interpret
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, nb),
         in_specs=[
-            pl.BlockSpec((1, h, dh), q_map),
+            pl.BlockSpec((1,) + qg.shape[1:], q_map),
             pl.BlockSpec((1, bsz) + pool_k.shape[2:], kv_map),
             pl.BlockSpec((1, bsz) + pool_v.shape[2:], kv_map),
         ],
-        out_specs=pl.BlockSpec((1, h, dh), q_map),
-        scratch_shapes=[
-            pltpu.VMEM((nb * bsz,) + pool_k.shape[2:], pool_k.dtype),
-            pltpu.VMEM((nb * bsz,) + pool_v.shape[2:], pool_v.dtype),
-        ],
+        out_specs=pl.BlockSpec((1,) + qg.shape[1:], q_map),
+        scratch_shapes=[_scratch(pool_k, nb), _scratch(pool_v, nb)],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, dh), pool_v.dtype),
-        compiler_params=tpu_compiler_params(("parallel", "arbitrary")),
+        out_shape=jax.ShapeDtypeStruct(qg.shape, pool_v.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")
+        ),
         interpret=interpret,
-    )(block_tables.astype(jnp.int32), pos.astype(jnp.int32), q, pool_k, pool_v)
+    )(block_tables.astype(jnp.int32), pos.astype(jnp.int32), qg, pool_k,
+      pool_v)
+    return out.reshape(b, h, dh)
 
 
 def _chunk_kernel(
@@ -164,18 +213,19 @@ def _chunk_kernel(
     lim_ref,  # (1,) int32 last logical block the chunk touches
     start_ref,  # (1,) int32 logical position of the chunk's first token
     # inputs
-    q_ref,  # (C, H, dh) chunk queries
+    q_ref,  # (G, KV, C, dh) chunk queries, head-major
     kb_ref,  # (1, bs, KV, hd)
     vb_ref,  # (1, bs, KV, hd)
     # outputs
-    out_ref,  # (C, H, dh)
+    out_ref,  # (G, KV, C, dh)
     # scratch
-    ks_ref,  # (S, KV, hd)
-    vs_ref,  # (S, KV, hd)
+    ks_ref,  # (KV, S, hd)
+    vs_ref,  # (KV, S, hd)
     *,
     bs: int,
     nb: int,
     window: int,
+    interpret: bool,
 ):
     kb = pl.program_id(0)
     lim = lim_ref[0]
@@ -188,31 +238,31 @@ def _chunk_kernel(
 
     @pl.when(kb <= lim)
     def _copy():
-        ks_ref[pl.ds(kb * bs, bs)] = kb_ref[0]
-        vs_ref[pl.ds(kb * bs, bs)] = vb_ref[0]
+        _copy_block(kb_ref, vb_ref, ks_ref, vs_ref, kb, bs)
 
     @pl.when(kb == nb - 1)
     def _attend():
-        # exact replica of attention_dense on the (S, KV, hd) scratch
-        c, h, dh = q_ref.shape
-        kvh = ks_ref.shape[1]
-        g = h // kvh
+        # exact replica of attention_dense on the (KV, S, hd) scratch,
+        # one query head per KV group at a time (bounds the (KV, C, S)
+        # score block)
+        g, kvh, c, dh = q_ref.shape
         scale = dh**-0.5
         k = ks_ref[...]
         v = vs_ref[...]
-        qg = q_ref[...].reshape(c, kvh, g, dh)
-        # _gqa_scores: no preferred_element_type — dtype promotion rules
-        # must match the dense path exactly
-        s = jnp.einsum("qkgd,skd->kgqs", qg, k).astype(jnp.float32) * scale
-        qi = start + jnp.arange(c)[:, None]
-        ki = jnp.arange(nb * bs)[None, :]
+        shape = (kvh, c, nb * bs)
+        qi = start + lax.broadcasted_iota(jnp.int32, shape, 1)
+        ki = lax.broadcasted_iota(jnp.int32, shape, 2)
         mask = ki <= qi
         if window > 0:
             mask &= ki > qi - window
-        s = jnp.where(mask[None, None], s, _NEG)
-        p = jax.nn.softmax(s, axis=-1)
-        o = jnp.einsum("kgqs,skd->qkgd", p.astype(v.dtype), v)
-        out_ref[...] = o.reshape(c, h, dh)
+        # _gqa_scores contracts in the promoted operand dtype: round the
+        # f32 accumulator to it before the f32 softmax
+        sdt = jnp.promote_types(q_ref.dtype, k.dtype)
+        for j in range(g):
+            s = _scores(q_ref[j], k, interpret).astype(sdt)
+            s = s.astype(jnp.float32) * scale
+            s = jnp.where(mask, s, _NEG)
+            out_ref[j] = _pv(jax.nn.softmax(s, axis=-1), v)
 
 
 def paged_chunk_attention(
@@ -255,32 +305,42 @@ def _paged_chunk_call(
 ):
     c, h, dh = q.shape
     bsz, nb = pool_k.shape[1], bt_row.shape[0]
+    kvh = pool_k.shape[2]
+    # head-major (G, KV, C, dh): each query head is a dense (C, dh) slab
+    qh = q.reshape(c, kvh, h // kvh, dh).transpose(2, 1, 0, 3)
 
     def q_map(kb, bt, lim, st):
-        return (0, 0, 0)
+        return (0, 0, 0, 0)
 
     def kv_map(kb, bt, lim, st):
         return (bt[jnp.minimum(kb, lim[0])], 0, 0, 0)
 
-    kernel = functools.partial(_chunk_kernel, bs=bsz, nb=nb, window=window)
+    kernel = functools.partial(
+        _chunk_kernel, bs=bsz, nb=nb, window=window, interpret=interpret
+    )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(nb,),
         in_specs=[
-            pl.BlockSpec((c, h, dh), q_map),
+            pl.BlockSpec(qh.shape, q_map),
             pl.BlockSpec((1, bsz) + pool_k.shape[2:], kv_map),
             pl.BlockSpec((1, bsz) + pool_v.shape[2:], kv_map),
         ],
-        out_specs=pl.BlockSpec((c, h, dh), q_map),
-        scratch_shapes=[
-            pltpu.VMEM((nb * bsz,) + pool_k.shape[2:], pool_k.dtype),
-            pltpu.VMEM((nb * bsz,) + pool_v.shape[2:], pool_v.dtype),
-        ],
+        out_specs=pl.BlockSpec(qh.shape, q_map),
+        scratch_shapes=[_scratch(pool_k, nb), _scratch(pool_v, nb)],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((c, h, dh), pool_v.dtype),
-        compiler_params=tpu_compiler_params(("arbitrary",)),
+        out_shape=jax.ShapeDtypeStruct(qh.shape, pool_v.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            # an f32 cache contracts at HIGHEST, which keeps bf16 hi/lo
+            # splits of K, V and each (KV, C, S) probability block beside
+            # them: ~23 MB at C=128, max_len=2048, over the 16 MB default
+            # scoped limit (a v5e core has 128 MiB of VMEM)
+            vmem_limit_bytes=_CHUNK_VMEM_LIMIT,
+        ),
         interpret=interpret,
-    )(bt_row, lim, start, q, pool_k, pool_v)
+    )(bt_row, lim, start, qh, pool_k, pool_v)
+    return out.transpose(2, 1, 0, 3).reshape(c, h, dh)

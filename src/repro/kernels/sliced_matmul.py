@@ -36,10 +36,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.slicing import SliceSpec, slice_significances
-
-from .compat import tpu_compiler_params
 
 __all__ = ["sliced_matmul_pallas", "fused_sliced_matmul_pallas"]
 
@@ -64,6 +63,16 @@ def _pin(x, interpret: bool):
     side of the contract.
     """
     return lax.optimization_barrier(x) if interpret else x
+
+
+def _slice_dot(x, w):
+    """One slice-pair crossbar read: an f32 contraction at full f32
+    precision — a TPU's default f32 matmul is one bf16 pass, which would
+    round the programmed (noisy) slice values to 8 mantissa bits."""
+    return jnp.dot(
+        x, w, precision=lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
+    )
 
 
 def _adc(p, i, j, *, bits_x, bits_w, bk, radc, adc_mode):
@@ -91,9 +100,9 @@ def _adc(p, i, j, *, bits_x, bits_w, bk, radc, adc_mode):
 
 def _kernel(
     xs_ref,  # (Sx, bm, bk)
-    sx_ref,  # (bm, 1)
+    sx_ref,  # (bm, 1) this k-block's input scales
     ws_ref,  # (Sw, bk, bn)
-    sw_ref,  # (1, 1)
+    sw_ref,  # (nk, nn) weight scale table, whole, in SMEM
     out_ref,  # (bm, bn) float32 accumulator
     *,
     sigx: tuple[float, ...],
@@ -106,7 +115,7 @@ def _kernel(
     nk: int,
     interpret: bool,
 ):
-    k = pl.program_id(2)
+    j_, k = pl.program_id(1), pl.program_id(2)
 
     @pl.when(k == 0)
     def _init():
@@ -117,13 +126,21 @@ def _kernel(
         xi = xs_ref[i].astype(jnp.float32)
         for j in range(len(sigw)):
             wj = ws_ref[j].astype(jnp.float32)
-            p = jnp.dot(xi, wj, preferred_element_type=jnp.float32)
+            p = _slice_dot(xi, wj)
             p = _adc(p, i, j, bits_x=bits_x, bits_w=bits_w, bk=bk,
                      radc=radc, adc_mode=adc_mode)
             acc = acc + _pin(jnp.float32(sigx[i] * sigw[j]) * p, interpret)
     # Per-block scales: sx is per (row, k-block), sw per (k-block, n-block).
-    acc = _pin(acc * sx_ref[...] * sw_ref[0, 0], interpret)
+    acc = _pin(acc * sx_ref[...] * sw_ref[k, j_], interpret)
     out_ref[...] += acc
+
+
+def _scale_table_spec():
+    """The (nk, nn) weight-scale table, whole, in SMEM: the kernel reads
+    one scalar per grid step at ``(k, j)``.  (A (1, 1) VMEM block of it
+    breaks Mosaic's rule that a block's last two dims be multiples of
+    (8, 128) or the array's own.)"""
+    return pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
 @functools.partial(
@@ -182,22 +199,25 @@ def sliced_matmul_pallas(
         interpret=interpret,
     )
     grid = (m // bm, nn, nk)
+    # input scales k-block-major (nk, M, 1): a (bm, 1) block of it keeps
+    # the array's own last dim, which Mosaic accepts
+    sx_t = sx.T[:, :, None]
     return pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((sxn, bm, bk), lambda i, j, k: (0, i, k)),
-            pl.BlockSpec((bm, 1), lambda i, j, k: (i, k)),
+            pl.BlockSpec((None, bm, 1), lambda i, j, k: (k, i, 0)),
             pl.BlockSpec((swn, bk, bn), lambda i, j, k: (0, k, j)),
-            pl.BlockSpec((1, 1), lambda i, j, k: (k, j)),
+            _scale_table_spec(),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, np_), jnp.float32),
-        compiler_params=tpu_compiler_params(
-            ("parallel", "parallel", "arbitrary")
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
-    )(xs, sx, ws, sw)
+    )(xs, sx_t, ws, sw)
 
 
 # ---------------------------------------------------------------------------
@@ -246,7 +266,7 @@ def _prep_input_tile(xt, *, spec: SliceSpec, rdac: int):
 def _fused_kernel(
     x_ref,  # (bm, bk) raw float input tile
     ws_ref,  # (Sw, bk, bn)
-    sw_ref,  # (1, 1)
+    sw_ref,  # (nk, nn) weight scale table, whole, in SMEM
     out_ref,  # (bm, bn) float32 accumulator
     *,
     input_spec: SliceSpec,
@@ -259,7 +279,7 @@ def _fused_kernel(
     adc_mode: str,
     interpret: bool,
 ):
-    k = pl.program_id(2)
+    j_, k = pl.program_id(1), pl.program_id(2)
 
     @pl.when(k == 0)
     def _init():
@@ -273,11 +293,11 @@ def _fused_kernel(
     for i in range(len(sigx)):
         for j in range(len(sigw)):
             wj = ws_ref[j].astype(jnp.float32)
-            p = jnp.dot(xs[i], wj, preferred_element_type=jnp.float32)
+            p = _slice_dot(xs[i], wj)
             p = _adc(p, i, j, bits_x=bits_x, bits_w=bits_w, bk=bk,
                      radc=radc, adc_mode=adc_mode)
             acc = acc + _pin(jnp.float32(sigx[i] * sigw[j]) * p, interpret)
-    acc = _pin(acc * sx * sw_ref[0, 0], interpret)
+    acc = _pin(acc * sx * sw_ref[k, j_], interpret)
     out_ref[...] += acc
 
 
@@ -355,12 +375,12 @@ def fused_sliced_matmul_pallas(
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
             pl.BlockSpec((swn, bk, bn), lambda i, j, k: (0, k, j)),
-            pl.BlockSpec((1, 1), lambda i, j, k: (k, j)),
+            _scale_table_spec(),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, np_), jnp.float32),
-        compiler_params=tpu_compiler_params(
-            ("parallel", "parallel", "arbitrary")
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
     )(x, ws, sw)

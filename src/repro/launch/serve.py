@@ -50,6 +50,7 @@ import numpy as np
 
 from repro import configs as arch_configs
 from repro.core import DriftModel
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.dryrun import make_policy
 from repro.models import init_params, program_params, programmed_byte_size
 from repro.serve import (
@@ -78,7 +79,9 @@ def main(argv=None):
     ap.add_argument("--shard_model", type=int, default=None,
                     help="shard the programmed state over N local devices "
                          "(model mesh axis, programmed_sharding_rules); "
-                         "default replicated")
+                         "default replicated.  A CPU host shows N devices "
+                         "only under XLA_FLAGS=--xla_force_host_platform_"
+                         "device_count=N, set before the launch")
     ap.add_argument("--requests", type=int, default=0,
                     help="serve N variable-length requests through the "
                          "continuous-batching engine instead of one "
@@ -182,15 +185,7 @@ def main(argv=None):
             _kops.set_kernels_enabled(True)
             _kops.set_interpret(False)
     shard_model = args.shard_model or 0
-    if shard_model > 1:
-        # must land before jax initialises its backends; only affects the
-        # host (CPU) platform — real accelerator device counts win
-        import os
-
-        os.environ["XLA_FLAGS"] = (
-            os.environ.get("XLA_FLAGS", "")
-            + f" --xla_force_host_platform_device_count={shard_model}"
-        ).strip()
+    use_compile_cache()
 
     cfg = (
         arch_configs.get_smoke(args.arch)

@@ -26,19 +26,23 @@ Execution paths:
   - ``pallas`` (``repro.kernels.paged_attention``): the kernel walks the
     block table in-kernel and reads only the mapped prefix blocks,
     O(prefix) per step; bitwise equal to the gather path in interpret
-    mode (tests/test_paged_attention.py).  The kernel path does not
-    carry the flash-decode sharding constraints — length-sharded TPU
-    meshes should pin the ``xla`` backend.
+    mode (tests/test_paged_attention.py).  Under a mesh the kernel runs
+    replicated on every device (``replicated_call``: Mosaic kernels
+    cannot be partitioned automatically), so it does not carry the
+    flash-decode sharding constraints — length-sharded TPU meshes
+    should pin the ``xla`` backend.
 
 All projections route through ``dense`` (mem-policy aware).
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.distributed.sharding import constrain
+from repro.distributed.sharding import constrain, replicated_call
 from repro.kernels import ops as _kops
 from repro.kernels.paged_attention import (
     paged_chunk_attention,
@@ -87,18 +91,30 @@ def _split_heads(x, n, hd):
     return x.reshape(*x.shape[:-1], n, hd)
 
 
+def _precision(*dtypes):
+    """Full f32 contraction for f32 operands: a TPU's default f32 matmul
+    is one bf16 pass (a CPU ignores the flag).  bf16 is exact in one."""
+    f32 = jnp.result_type(*dtypes) == jnp.float32
+    return lax.Precision.HIGHEST if f32 else None
+
+
 def _gqa_scores(q, k):
     """q: (B,Sq,H,dh), k: (B,Skv,KV,dh) -> scores (B,KV,H/KV,Sq,Skv)."""
     b, sq, h, dh = q.shape
     kv = k.shape[2]
     qg = q.reshape(b, sq, kv, h // kv, dh)
-    return jnp.einsum("bqkgd,bskd->bkgqs", qg, k)
+    return jnp.einsum(
+        "bqkgd,bskd->bkgqs", qg, k, precision=_precision(q.dtype, k.dtype)
+    )
 
 
 def _gqa_out(probs, v):
     """probs: (B,KV,G,Sq,Skv), v: (B,Skv,KV,dh) -> (B,Sq,KV*G,dh)."""
     b, kv, g, sq, skv = probs.shape
-    out = jnp.einsum("bkgqs,bskd->bqkgd", probs, v)
+    out = jnp.einsum(
+        "bkgqs,bskd->bqkgd", probs, v,
+        precision=_precision(probs.dtype, v.dtype),
+    )
     return out.reshape(b, sq, kv * g, out.shape[-1])
 
 
@@ -247,6 +263,7 @@ def attention_decode(q1, k_cache, v_cache, pos, *, window=0):
             "bkgd,bskd->bkgs",
             qg.astype(k_cache.dtype),
             k_cache,
+            precision=_precision(k_cache.dtype),
             preferred_element_type=jnp.float32,
         )
         * scale
@@ -259,7 +276,10 @@ def attention_decode(q1, k_cache, v_cache, pos, *, window=0):
     m = jnp.max(s, axis=-1, keepdims=True)
     p = jnp.exp(s - m)
     l = jnp.sum(p, axis=-1, keepdims=True)
-    out = jnp.einsum("bkgs,bskd->bkgd", (p / l).astype(v_cache.dtype), v_cache)
+    out = jnp.einsum(
+        "bkgs,bskd->bkgd", (p / l).astype(v_cache.dtype), v_cache,
+        precision=_precision(v_cache.dtype),
+    )
     return out.reshape(b, h, dh)
 
 
@@ -335,6 +355,7 @@ def attention_verify(q, k_cache, v_cache, pos, *, window=0):
             "bckgd,bskd->bckgs",
             qg.astype(k_cache.dtype),
             k_cache,
+            precision=_precision(k_cache.dtype),
             preferred_element_type=jnp.float32,
         )
         * scale
@@ -349,7 +370,8 @@ def attention_verify(q, k_cache, v_cache, pos, *, window=0):
     p = jnp.exp(s - m)
     l = jnp.sum(p, axis=-1, keepdims=True)
     out = jnp.einsum(
-        "bckgs,bskd->bckgd", (p / l).astype(v_cache.dtype), v_cache
+        "bckgs,bskd->bckgd", (p / l).astype(v_cache.dtype), v_cache,
+        precision=_precision(v_cache.dtype),
     )
     return out.reshape(b, c, h, dh)
 
@@ -519,9 +541,12 @@ def decode_attention_block(
         and _kops.resolve_attention_backend() == "pallas"
     ):
         # in-kernel block walk: only the mapped prefix blocks are read
-        out = paged_decode_attention(
+        out = replicated_call(
+            functools.partial(
+                paged_decode_attention, window=window,
+                interpret=_kops.kernel_interpret(),
+            ),
             q, cache_k, cache_v, block_tables, pos,
-            window=window, interpret=_kops.kernel_interpret(),
         )
     else:
         if block_tables is not None:
@@ -598,9 +623,12 @@ def chunk_attention_block(
         # Pad queries (>= n_valid) see a zero tail instead of the stale
         # gathered junk — their outputs are discarded by the caller, the
         # valid rows are bitwise equal (tests/test_paged_attention.py).
-        out = paged_chunk_attention(
+        out = replicated_call(
+            functools.partial(
+                paged_chunk_attention, window=cfg.swa_window,
+                interpret=_kops.kernel_interpret(),
+            ),
             q, pool_k, pool_v, bt_row, start, n_valid,
-            window=cfg.swa_window, interpret=_kops.kernel_interpret(),
         )
     else:
         g_k = _paged_gather(pool_k, bt_row[None])

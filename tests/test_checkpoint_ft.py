@@ -141,11 +141,9 @@ def test_compressed_psum_single_device():
     from jax.sharding import PartitionSpec as P
 
     from repro.distributed.compression import compressed_psum
-    from repro.distributed.sharding import shard_map
-
     mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
     x = jax.random.normal(jax.random.PRNGKey(2), (64,))
-    f = shard_map(
+    f = jax.shard_map(
         lambda a: compressed_psum(a, "data"),
         mesh=mesh,
         in_specs=P(),
