@@ -151,6 +151,8 @@ def _scratch(pool, nb):
     return pltpu.VMEM((kvh, nb * pool.shape[1], hd), pool.dtype)
 
 
+# jitted under its own name, the kernel's operation in the compiled
+# program is named ``paged_decode_attention``: the benchmark reads it
 @functools.partial(
     jax.jit, static_argnames=("window", "interpret")
 )
@@ -299,6 +301,8 @@ def paged_chunk_attention(
     )[None]
 
 
+# jitted under its own name, the kernel's operation in the compiled
+# program is named ``_paged_chunk_call``: the benchmark reads it
 @functools.partial(jax.jit, static_argnames=("window", "interpret"))
 def _paged_chunk_call(
     q, pool_k, pool_v, bt_row, lim, start, *, window, interpret

@@ -301,6 +301,8 @@ def _fused_kernel(
     out_ref[...] += acc
 
 
+# jitted under its own name, the kernel's operation in the compiled
+# program is named ``fused_sliced_matmul_pallas``: the benchmark reads it
 @functools.partial(
     jax.jit,
     static_argnames=(

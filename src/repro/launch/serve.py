@@ -448,6 +448,14 @@ def _serve_continuous(args, cfg, policy, params, programmed, mesh):
             )
         )
     print("counters:", report.counters())
+    spans = {
+        name: [calls, round(seconds, 6)]
+        for name, (calls, seconds) in sorted(report.host_spans.items())
+    }
+    print(f"host spans [calls, s]: {spans}")
+    print(f"host gap: {report.host_gap_s:.6f}s of {report.wall_s:.6f}s; "
+          f"compiled while serving: {report.window_compiles} "
+          f"{report.compiled}")
     print("sample:", report.results[0].tokens[:16])
     return report
 

@@ -70,6 +70,7 @@ from .config import ReproDeprecationWarning, ServeConfig
 from .engine import make_chunk_prefill, make_decode_step, make_verify_step
 from .prefix_cache import PrefixCache
 from .sampling import SamplingParams, request_keys, sample_row, sample_rows
+from .spans import Recorder
 
 __all__ = [
     "Request",
@@ -243,7 +244,20 @@ class ServeReport:
     scheduler activity — ``{"chunks": prefill chunks run, "decoded":
     lanes decoded, "admitted": [rid, ...] in admission order,
     "deferred": deferral events this iteration}`` — for starvation and
-    deferral-semantics analysis."""
+    deferral-semantics analysis.
+
+    Host records of the run (``serve/spans.py``; kept out of
+    ``COUNTER_FIELDS``, since a compile count depends on the process's
+    cache state): ``host_spans`` maps each span name to ``[calls,
+    seconds]`` of host time; ``host_gap_s`` sums the host time from the
+    first device sync after a dispatch to the next jitted call, outside
+    ``serve.wait`` — the idle gaps the host causes after the device
+    drains; ``compiled`` names every program XLA compiled or loaded from
+    the persistent cache while ``run()`` was in flight, and
+    ``window_compiles`` counts them.  A request's phases are its own
+    stamps on the loop's clock: queue from ``submit_time`` to
+    ``admit_time``, prefill (``prefill_chunks`` chunks) from there to
+    ``first_token_time``, decode from there to ``finish_time``."""
 
     results: list[RequestResult]
     wall_s: float
@@ -264,6 +278,10 @@ class ServeReport:
     tokens_drafted: int = 0
     tokens_accepted: int = 0
     trace: list | None = None
+    host_spans: dict = field(default_factory=dict)
+    host_gap_s: float = 0.0
+    window_compiles: int = 0
+    compiled: list = field(default_factory=list)
 
     #: the stable counter surface — ``counters()`` keys, in order.  New
     #: counters are added HERE (and to the dataclass), so callers consume
@@ -573,6 +591,8 @@ def _jit_decode_cached(cfg, policy, compute_dtype, mesh, kernel_state,
     # two step functions trace DIFFERENT graphs over the same leading
     # arguments, so a loop flipped from greedy to sampled (or back)
     # between constructions must never reuse the other mode's trace.
+    # Both are named ``step``, so both run as the module ``jit_step``:
+    # the benchmark reads that name in the device trace.
     if sampled:
         def step(params, cache, tokens, programmed, active, t_now,
                  keys, temps, top_ks, top_ps):
@@ -1170,15 +1190,21 @@ class ServeLoop:
             rules_context(self.mesh) if self.mesh is not None
             else contextlib.nullcontext()
         )
-        with ctx:
-            report = self._run(live)
+        rec = Recorder()
+        with rec.listening(), ctx:
+            report = self._run(live, rec)
+        report.host_spans = rec.totals
+        report.host_gap_s = rec.gap_s
+        report.compiled = rec.compiled
+        report.window_compiles = len(rec.compiled)
         if refused:
             by_rid = {res.rid: res for res in report.results}
             by_rid.update(refused)
             report.results = [by_rid[r.rid] for r in requests]
         return report
 
-    def _run(self, requests) -> ServeReport:
+    def _run(self, requests, rec: Recorder) -> ServeReport:
+        span, dispatch = rec.span, rec.dispatch
         queue = RequestQueue(
             interactive_weight=self.interactive_weight,
             max_queue_skip=self.max_queue_skip,
@@ -1253,400 +1279,446 @@ class ServeLoop:
         )
 
         while len(results) < len(requests):
-            # 0. one device-clock sample per iteration: every chunk and
-            # decode call of this iteration evaluates drift at the same
-            # instant, and the refresh trigger compares against it
-            t_dev = dev_clock()
-            t_arg = jnp.float32(t_dev) if self._drift_on else None
-            if next_refresh is not None and t_dev >= next_refresh:
-                draining = any(
-                    st is not None and st.gen != self.generation
-                    for st in slot_state
+            with span("serve.iteration") as it:
+                # 0. one device-clock sample per iteration: every chunk and
+                # decode call of this iteration evaluates drift at the same
+                # instant, and the refresh trigger compares against it
+                t_dev = dev_clock()
+                t_arg = jnp.float32(t_dev) if self._drift_on else None
+                if next_refresh is not None and t_dev >= next_refresh:
+                    draining = any(
+                        st is not None and st.gen != self.generation
+                        for st in slot_state
+                    )
+                    if not draining:
+                        # generation N+1: fresh programming noise
+                        # (fold_in(key0, gen)) and a fresh t_prog stamp,
+                        # built SHARDED like generation 0.  JAX dispatches
+                        # the programming pass asynchronously — generation N
+                        # keeps decoding below while it materialises; only a
+                        # lane that later pins gen N+1 ever blocks on it.
+                        # At most two generations are live: while old-gen
+                        # lanes drain, the next refresh waits (the
+                        # double-buffer bound on transient memory).
+                        self.generation += 1
+                        key = jax.random.fold_in(
+                            jax.random.PRNGKey(0), self.generation
+                        )
+                        with span("serve.refresh", gen=self.generation):
+                            dispatch()
+                            self.programmed = program_params(
+                                self.params, self.cfg, self.policy, key,
+                                mesh=self.mesh, t_prog=t_dev,
+                            )
+                        swaps += 1
+                        next_refresh = t_dev + self.refresh_every
+                # 1. admit: the scheduler binds ready requests to free
+                # lanes per the DESIGN.md §7 rules (aging bound first, then
+                # weighted round-robin over classes, then the cache-aware
+                # pool-feasible pick), eagerly allocating each pick's full
+                # KV block need; a pool-starved request waits for a
+                # retirement unless a bounded skip-ahead can fill the lane
+                def_before = queue.deferrals
+                admitted_now: list[int] = []
+                probe = (
+                    (lambda rq: self._blocks.resident_prefix_len(rq.tokens))
+                    if self.prefix_cache else None
                 )
-                if not draining:
-                    # generation N+1: fresh programming noise
-                    # (fold_in(key0, gen)) and a fresh t_prog stamp,
-                    # built SHARDED like generation 0.  JAX dispatches
-                    # the programming pass asynchronously — generation N
-                    # keeps decoding below while it materialises; only a
-                    # lane that later pins gen N+1 ever blocks on it.
-                    # At most two generations are live: while old-gen
-                    # lanes drain, the next refresh waits (the
-                    # double-buffer bound on transient memory).
-                    self.generation += 1
-                    key = jax.random.fold_in(
-                        jax.random.PRNGKey(0), self.generation
-                    )
-                    self.programmed = program_params(
-                        self.params, self.cfg, self.policy, key,
-                        mesh=self.mesh, t_prog=t_dev,
-                    )
-                    swaps += 1
-                    next_refresh = t_dev + self.refresh_every
-            # 1. admit: the scheduler binds ready requests to free
-            # lanes per the DESIGN.md §7 rules (aging bound first, then
-            # weighted round-robin over classes, then the cache-aware
-            # pool-feasible pick), eagerly allocating each pick's full
-            # KV block need; a pool-starved request waits for a
-            # retirement unless a bounded skip-ahead can fill the lane
-            def_before = queue.deferrals
-            admitted_now: list[int] = []
-            probe = (
-                (lambda rq: self._blocks.resident_prefix_len(rq.tokens))
-                if self.prefix_cache else None
-            )
-            for k in range(K):
-                if slot_state[k] is not None:
-                    continue
-                sel = queue.select(
-                    now(),
-                    lambda rq: self._blocks.admit(
-                        rq.tokens, self._blocks_needed(rq)
-                    ),
-                    probe=probe,
-                )
-                if sel is None:
-                    break
-                r, plan = sel
-                admitted_now.append(r.rid)
-                bt_row = np.zeros((self.blocks_per_slot,), np.int32)
-                bt_row[: len(plan.blocks)] = plan.blocks
-                cache = self._admit(
-                    cache, jnp.int32(k), jnp.asarray(bt_row)
-                )
-                if plan.cow is not None:
-                    # the one device cost of sharing: clone the shared
-                    # block this lane is about to write into
-                    src, dst = plan.cow
-                    cache = self._copy(
-                        cache, jnp.int32(src), jnp.int32(dst)
-                    )
-                sp = r.sampling
-                n_keys = r.max_new_tokens + self.spec_k + 1
-                if sp is not None and sp.temperature > 0:
-                    keys = np.asarray(request_keys(sp.seed, n_keys))
-                    temp = float(sp.temperature)
-                    tk, tp = int(sp.top_k), float(sp.top_p)
-                else:
-                    # greedy (or temperature=0 sampling, the same
-                    # thing): keys never reach a draw
-                    keys = np.zeros((n_keys, 2), np.uint32)
-                    temp, tk, tp = 0.0, 0, 1.0
-                slot_temp[k], slot_topk[k], slot_topp[k] = temp, tk, tp
-                slot_state[k] = _SlotState(
-                    request=r,
-                    admit_time=now(),
-                    plan=plan,
-                    # swap boundary: a request takes the generation that
-                    # is current AT ADMISSION and keeps it to retirement
-                    programmed=self.programmed,
-                    gen=self.generation,
-                    prefill_pos=plan.resume_pos,
-                    logits=[] if self.collect_logits else None,
-                    keys=keys,
-                    temp=temp,
-                    top_k=tk,
-                    top_p=tp,
-                )
-                active[k] = False
-                if spec:
-                    # bind the draft lane to its static block range and
-                    # reset its pos; the draft prefills the FULL prompt
-                    # from 0 (its arena shares nothing with the target's
-                    # prefix cache)
-                    draft_bt = np.arange(
-                        1 + k * self.blocks_per_slot,
-                        1 + (k + 1) * self.blocks_per_slot,
-                        dtype=np.int32,
-                    )
-                    draft_cache = self._admit(
-                        draft_cache, jnp.int32(k), jnp.asarray(draft_bt)
-                    )
+                for k in range(K):
+                    if slot_state[k] is not None:
+                        continue
+                    # one span per pick: every admitted lane (with its rid),
+                    # and the iteration's last pick that found none
+                    with span("serve.admit") as adm:
+                        sel = queue.select(
+                            now(),
+                            lambda rq: self._blocks.admit(
+                                rq.tokens, self._blocks_needed(rq)
+                            ),
+                            probe=probe,
+                        )
+                        if sel is None:
+                            break
+                        r, plan = sel
+                        adm.set_metadata(rid=r.rid)
+                        admitted_now.append(r.rid)
+                        bt_row = np.zeros((self.blocks_per_slot,), np.int32)
+                        bt_row[: len(plan.blocks)] = plan.blocks
+                        dispatch()
+                        cache = self._admit(
+                            cache, jnp.int32(k), jnp.asarray(bt_row)
+                        )
+                        if plan.cow is not None:
+                            # the one device cost of sharing: clone the shared
+                            # block this lane is about to write into
+                            src, dst = plan.cow
+                            dispatch()
+                            cache = self._copy(
+                                cache, jnp.int32(src), jnp.int32(dst)
+                            )
+                        sp = r.sampling
+                        n_keys = r.max_new_tokens + self.spec_k + 1
+                        if sp is not None and sp.temperature > 0:
+                            with span("serve.sync", what="keys", rid=r.rid):
+                                keys = np.asarray(
+                                    request_keys(sp.seed, n_keys)
+                                )
+                            temp = float(sp.temperature)
+                            tk, tp = int(sp.top_k), float(sp.top_p)
+                        else:
+                            # greedy (or temperature=0 sampling, the same
+                            # thing): keys never reach a draw
+                            keys = np.zeros((n_keys, 2), np.uint32)
+                            temp, tk, tp = 0.0, 0, 1.0
+                        slot_temp[k], slot_topk[k], slot_topp[k] = temp, tk, tp
+                        slot_state[k] = _SlotState(
+                            request=r,
+                            admit_time=now(),
+                            plan=plan,
+                            # swap boundary: a request takes the generation
+                            # that is current AT ADMISSION and keeps it to
+                            # retirement
+                            programmed=self.programmed,
+                            gen=self.generation,
+                            prefill_pos=plan.resume_pos,
+                            logits=[] if self.collect_logits else None,
+                            keys=keys,
+                            temp=temp,
+                            top_k=tk,
+                            top_p=tp,
+                        )
+                        active[k] = False
+                        if spec:
+                            # bind the draft lane to its static block range
+                            # and reset its pos; the draft prefills the FULL
+                            # prompt from 0 (its arena shares nothing with
+                            # the target's prefix cache)
+                            draft_bt = np.arange(
+                                1 + k * self.blocks_per_slot,
+                                1 + (k + 1) * self.blocks_per_slot,
+                                dtype=np.int32,
+                            )
+                            dispatch()
+                            draft_cache = self._admit(
+                                draft_cache, jnp.int32(k),
+                                jnp.asarray(draft_bt),
+                            )
 
-            # 2. one prefill chunk per still-prefilling lane — admission
-            # work is spread so it never stalls the decode step below.
-            # With speculation each lane ALSO advances its draft-engine
-            # prefill by one chunk per iteration (own cache, full
-            # prompt); the lane only starts decoding once both engines
-            # hold the prompt, but the first token always comes from the
-            # target's final chunk.
-            chunks_run = 0
-            draft_chunks = 0
-            for k in range(K):
-                st = slot_state[k]
-                if st is None or active[k]:
-                    continue
-                r = st.request
-                plen = len(r.tokens)
-                if st.prefill_pos < plen:
-                    start = st.prefill_pos
-                    # a cached prefix shrinks the remaining prompt — the
-                    # unchunked bucket covers only what is left to run
-                    clen = (
-                        self.prefill_chunk
-                        or self._bucket_for(plen - start)
+                # 2. one prefill chunk per still-prefilling lane — admission
+                # work is spread so it never stalls the decode step below.
+                # With speculation each lane ALSO advances its draft-engine
+                # prefill by one chunk per iteration (own cache, full
+                # prompt); the lane only starts decoding once both engines
+                # hold the prompt, but the first token always comes from the
+                # target's final chunk.
+                chunks_run = 0
+                draft_chunks = 0
+                for k in range(K):
+                    st = slot_state[k]
+                    if st is None or active[k]:
+                        continue
+                    r = st.request
+                    plen = len(r.tokens)
+                    if st.prefill_pos < plen:
+                        start = st.prefill_pos
+                        # a cached prefix shrinks the remaining prompt — the
+                        # unchunked bucket covers only what is left to run
+                        clen = (
+                            self.prefill_chunk
+                            or self._bucket_for(plen - start)
+                        )
+                        nv = min(clen, plen - start)
+                        toks = np.zeros((clen,), np.int32)
+                        toks[:nv] = np.asarray(
+                            r.tokens[start:start + nv], np.int32
+                        )
+                        with span("serve.chunk", rid=r.rid, start=start):
+                            dispatch()
+                            logits, cache = self._chunk(
+                                self.params, cache, jnp.asarray(toks),
+                                jnp.int32(k), jnp.int32(start), jnp.int32(nv),
+                                jnp.bool_(start + nv >= plen), st.programmed,
+                                t_arg,
+                            )
+                        st.prefill_pos = start + nv
+                        st.prefill_chunks += 1
+                        chunks_run += 1
+                        self._blocks.register_progress(st.plan, st.prefill_pos)
+                        if st.prefill_pos >= plen:  # final chunk → 1st token
+                            with span("serve.sync", what="first_token",
+                                      rid=r.rid):
+                                if st.temp > 0:
+                                    # emission index 0 draws with keys[0] —
+                                    # the same single-row sampler the solo
+                                    # oracle vmaps, so the draw is bitwise
+                                    # theirs
+                                    t_first = int(
+                                        _jit_sample1()(
+                                            jnp.asarray(st.keys[0]), logits[0],
+                                            st.temp, st.top_k, st.top_p,
+                                        )
+                                    )
+                                else:
+                                    t_first = int(jnp.argmax(logits[0]))
+                            st.first_token_time = now()
+                            generated += 1
+                            if self._emit(st, t_first, logits[0]):
+                                results[r.rid] = self._result(st, now())
+                                self._blocks.release(st.plan)
+                                slot_state[k] = None
+                                continue
+                            next_tok[k] = t_first
+                    if spec and st.draft_pos < plen:
+                        start = st.draft_pos
+                        clen = (
+                            self.prefill_chunk
+                            or self._bucket_for(plen - start)
+                        )
+                        nv = min(clen, plen - start)
+                        toks = np.zeros((clen,), np.int32)
+                        toks[:nv] = np.asarray(
+                            r.tokens[start:start + nv], np.int32
+                        )
+                        # final=False: the draft never needs prefill logits
+                        # (its first proposal samples AFTER consuming the
+                        # target's first token), so the vocab projection is
+                        # skipped while pos still advances to plen
+                        with span("serve.chunk", rid=r.rid, start=start):
+                            dispatch()
+                            _, draft_cache = self._draft_chunk(
+                                self.params, draft_cache, jnp.asarray(toks),
+                                jnp.int32(k), jnp.int32(start), jnp.int32(nv),
+                                jnp.bool_(False), self.draft_programmed, t_arg,
+                            )
+                        st.draft_pos = start + nv
+                        draft_chunks += 1
+                    if (
+                        st.prefill_pos >= plen
+                        and (not spec or st.draft_pos >= plen)
+                        and st.out
+                    ):
+                        active[k] = True
+
+                # 3. slot-parallel decode over the active lanes — one jitted
+                # call per LIVE GENERATION (normally exactly one; during a
+                # post-refresh drain, one for the old-gen lanes and one for
+                # the new, with complementary active masks — inactive lanes
+                # write only the trash block, so the calls compose)
+                decoded = int(active.sum())
+                if decoded and not spec:
+                    gens = sorted(
+                        {slot_state[k].gen for k in range(K) if active[k]}
                     )
-                    nv = min(clen, plen - start)
-                    toks = np.zeros((clen,), np.int32)
-                    toks[:nv] = np.asarray(
-                        r.tokens[start:start + nv], np.int32
+                    toks_np = np.zeros((K,), np.int32)
+                    logits_np = None
+                    extra = ()
+                    if run_sampled:
+                        # emission index of the token this step draws =
+                        # len(out); the key is a pure function of (seed,
+                        # index), so the packing never enters the draw
+                        keys_now = np.zeros((K, 2), np.uint32)
+                        for k in range(K):
+                            if active[k]:
+                                st = slot_state[k]
+                                keys_now[k] = st.keys[len(st.out)]
+                        extra = (
+                            jnp.asarray(keys_now), jnp.asarray(slot_temp),
+                            jnp.asarray(slot_topk), jnp.asarray(slot_topp),
+                        )
+                    for g in gens:
+                        mask = np.array(
+                            [
+                                bool(active[k]) and slot_state[k].gen == g
+                                for k in range(K)
+                            ]
+                        )
+                        prog = next(
+                            slot_state[k].programmed
+                            for k in range(K)
+                            if mask[k]
+                        )
+                        with span("serve.decode"):
+                            dispatch()
+                            logits, toks, cache = decode(
+                                self.params, cache, jnp.asarray(next_tok),
+                                prog, jnp.asarray(mask), t_arg, *extra,
+                            )
+                        decode_steps += 1
+                        occupancy += int(mask.sum())
+                        with span("serve.sync", what="tokens"):
+                            toks_np[mask] = np.asarray(toks)[mask]
+                        if self.collect_logits:
+                            with span("serve.sync", what="logits"):
+                                l_np = np.asarray(logits)
+                            if logits_np is None:
+                                logits_np = np.zeros_like(l_np)
+                            logits_np[mask] = l_np[mask]
+                    with span("serve.emit"):
+                        for k in range(K):
+                            if not active[k]:
+                                continue
+                            st = slot_state[k]
+                            st.decode_steps += 1
+                            generated += 1
+                            t = int(toks_np[k])
+                            row = (
+                                logits_np[k] if logits_np is not None
+                                else None
+                            )
+                            if self._emit(st, t, row):
+                                results[st.request.rid] = self._result(
+                                    st, now()
+                                )
+                                self._blocks.release(st.plan)
+                                slot_state[k] = None
+                                active[k] = False
+                            else:
+                                next_tok[k] = t
+                elif decoded:
+                    # speculative round, one per live generation: draft
+                    # proposes spec_k tokens on its own cache, the target
+                    # verifies all C = spec_k+1 positions in ONE batched
+                    # multi-token forward, and the host accepts the longest
+                    # prefix of drafts that match what the target itself
+                    # emits — so the emitted tokens are EXACTLY the
+                    # non-speculative trajectory and only throughput moves
+                    gens = sorted(
+                        {slot_state[k].gen for k in range(K) if active[k]}
                     )
-                    logits, cache = self._chunk(
-                        self.params, cache, jnp.asarray(toks),
-                        jnp.int32(k), jnp.int32(start), jnp.int32(nv),
-                        jnp.bool_(start + nv >= plen), st.programmed,
-                        t_arg,
-                    )
-                    st.prefill_pos = start + nv
-                    st.prefill_chunks += 1
-                    chunks_run += 1
-                    self._blocks.register_progress(st.plan, st.prefill_pos)
-                    if st.prefill_pos >= plen:  # final chunk → 1st token
-                        if st.temp > 0:
-                            # emission index 0 draws with keys[0] — the
-                            # same single-row sampler the solo oracle
-                            # vmaps, so the draw is bitwise theirs
-                            t_first = int(
-                                _jit_sample1()(
-                                    jnp.asarray(st.keys[0]), logits[0],
-                                    st.temp, st.top_k, st.top_p,
+                    temps = jnp.asarray(slot_temp)
+                    tks_a = jnp.asarray(slot_topk)
+                    tps_a = jnp.asarray(slot_topp)
+                    for g in gens:
+                        mask = np.array(
+                            [
+                                bool(active[k]) and slot_state[k].gen == g
+                                for k in range(K)
+                            ]
+                        )
+                        prog = next(
+                            slot_state[k].programmed
+                            for k in range(K)
+                            if mask[k]
+                        )
+                        # keys: draft step j and verify column c both draw
+                        # emission index e0+j / e0+c of their slot — the
+                        # SAME key on (numerically different) logits; a
+                        # matching draw is exactly an accepted draft
+                        keys_d = np.zeros((self.spec_k, K, 2), np.uint32)
+                        keys_v = np.zeros((K, C, 2), np.uint32)
+                        for k in range(K):
+                            if not mask[k]:
+                                continue
+                            st = slot_state[k]
+                            e0 = len(st.out)
+                            keys_d[:, k] = st.keys[e0:e0 + self.spec_k]
+                            keys_v[k] = st.keys[e0:e0 + C]
+                        # the accepted frontier going INTO this round, for
+                        # every slot (a previous round's draft scan left
+                        # pos past it; verify never advances it): active =
+                        # one past the last emitted token's KV, prefilling
+                        # = the chunk frontier, free = parked at 0
+                        pos_t = np.zeros((K,), np.int32)
+                        pos_d = np.zeros((K,), np.int32)
+                        for k in range(K):
+                            st = slot_state[k]
+                            if st is None:
+                                continue
+                            if active[k]:
+                                pos_t[k] = (
+                                    len(st.request.tokens) + len(st.out) - 1
+                                )
+                                pos_d[k] = pos_t[k]
+                            else:
+                                pos_t[k] = st.prefill_pos
+                                pos_d[k] = st.draft_pos
+                        with span("serve.decode"):
+                            dispatch()
+                            logits, toks_v, tokens_c, cache, draft_cache = (
+                                self._spec_round(
+                                    self.params, cache, draft_cache,
+                                    jnp.asarray(next_tok), jnp.asarray(pos_t),
+                                    jnp.asarray(pos_d), prog,
+                                    self.draft_programmed, jnp.asarray(mask),
+                                    t_arg, jnp.asarray(keys_d),
+                                    jnp.asarray(keys_v), temps, tks_a, tps_a,
                                 )
                             )
-                        else:
-                            t_first = int(jnp.argmax(logits[0]))
-                        st.first_token_time = now()
-                        generated += 1
-                        if self._emit(st, t_first, logits[0]):
-                            results[r.rid] = self._result(st, now())
-                            self._blocks.release(st.plan)
-                            slot_state[k] = None
-                            continue
-                        next_tok[k] = t_first
-                if spec and st.draft_pos < plen:
-                    start = st.draft_pos
-                    clen = (
-                        self.prefill_chunk
-                        or self._bucket_for(plen - start)
-                    )
-                    nv = min(clen, plen - start)
-                    toks = np.zeros((clen,), np.int32)
-                    toks[:nv] = np.asarray(
-                        r.tokens[start:start + nv], np.int32
-                    )
-                    # final=False: the draft never needs prefill logits
-                    # (its first proposal samples AFTER consuming the
-                    # target's first token), so the vocab projection is
-                    # skipped while pos still advances to plen
-                    _, draft_cache = self._draft_chunk(
-                        self.params, draft_cache, jnp.asarray(toks),
-                        jnp.int32(k), jnp.int32(start), jnp.int32(nv),
-                        jnp.bool_(False), self.draft_programmed, t_arg,
-                    )
-                    st.draft_pos = start + nv
-                    draft_chunks += 1
-                if (
-                    st.prefill_pos >= plen
-                    and (not spec or st.draft_pos >= plen)
-                    and st.out
-                ):
-                    active[k] = True
-
-            # 3. slot-parallel decode over the active lanes — one jitted
-            # call per LIVE GENERATION (normally exactly one; during a
-            # post-refresh drain, one for the old-gen lanes and one for
-            # the new, with complementary active masks — inactive lanes
-            # write only the trash block, so the calls compose)
-            decoded = int(active.sum())
-            if decoded and not spec:
-                gens = sorted(
-                    {slot_state[k].gen for k in range(K) if active[k]}
-                )
-                toks_np = np.zeros((K,), np.int32)
-                logits_np = None
-                extra = ()
-                if run_sampled:
-                    # emission index of the token this step draws =
-                    # len(out); the key is a pure function of (seed,
-                    # index), so the packing never enters the draw
-                    keys_now = np.zeros((K, 2), np.uint32)
-                    for k in range(K):
-                        if active[k]:
-                            st = slot_state[k]
-                            keys_now[k] = st.keys[len(st.out)]
-                    extra = (
-                        jnp.asarray(keys_now), jnp.asarray(slot_temp),
-                        jnp.asarray(slot_topk), jnp.asarray(slot_topp),
-                    )
-                for g in gens:
-                    mask = np.array(
-                        [
-                            bool(active[k]) and slot_state[k].gen == g
-                            for k in range(K)
-                        ]
-                    )
-                    prog = next(
-                        slot_state[k].programmed
-                        for k in range(K)
-                        if mask[k]
-                    )
-                    logits, toks, cache = decode(
-                        self.params, cache, jnp.asarray(next_tok),
-                        prog, jnp.asarray(mask), t_arg, *extra,
-                    )
-                    decode_steps += 1
-                    occupancy += int(mask.sum())
-                    toks_np[mask] = np.asarray(toks)[mask]
-                    if self.collect_logits:
-                        l_np = np.asarray(logits)
-                        if logits_np is None:
-                            logits_np = np.zeros_like(l_np)
-                        logits_np[mask] = l_np[mask]
-                for k in range(K):
-                    if not active[k]:
-                        continue
-                    st = slot_state[k]
-                    st.decode_steps += 1
-                    generated += 1
-                    t = int(toks_np[k])
-                    row = logits_np[k] if logits_np is not None else None
-                    if self._emit(st, t, row):
-                        results[st.request.rid] = self._result(st, now())
-                        self._blocks.release(st.plan)
-                        slot_state[k] = None
-                        active[k] = False
-                    else:
-                        next_tok[k] = t
-            elif decoded:
-                # speculative round, one per live generation: draft
-                # proposes spec_k tokens on its own cache, the target
-                # verifies all C = spec_k+1 positions in ONE batched
-                # multi-token forward, and the host accepts the longest
-                # prefix of drafts that match what the target itself
-                # emits — so the emitted tokens are EXACTLY the
-                # non-speculative trajectory and only throughput moves
-                gens = sorted(
-                    {slot_state[k].gen for k in range(K) if active[k]}
-                )
-                temps = jnp.asarray(slot_temp)
-                tks_a = jnp.asarray(slot_topk)
-                tps_a = jnp.asarray(slot_topp)
-                for g in gens:
-                    mask = np.array(
-                        [
-                            bool(active[k]) and slot_state[k].gen == g
-                            for k in range(K)
-                        ]
-                    )
-                    prog = next(
-                        slot_state[k].programmed
-                        for k in range(K)
-                        if mask[k]
-                    )
-                    # keys: draft step j and verify column c both draw
-                    # emission index e0+j / e0+c of their slot — the
-                    # SAME key on (numerically different) logits; a
-                    # matching draw is exactly an accepted draft
-                    keys_d = np.zeros((self.spec_k, K, 2), np.uint32)
-                    keys_v = np.zeros((K, C, 2), np.uint32)
-                    for k in range(K):
-                        if not mask[k]:
-                            continue
-                        st = slot_state[k]
-                        e0 = len(st.out)
-                        keys_d[:, k] = st.keys[e0:e0 + self.spec_k]
-                        keys_v[k] = st.keys[e0:e0 + C]
-                    # the accepted frontier going INTO this round, for
-                    # every slot (a previous round's draft scan left
-                    # pos past it; verify never advances it): active =
-                    # one past the last emitted token's KV, prefilling
-                    # = the chunk frontier, free = parked at 0
-                    pos_t = np.zeros((K,), np.int32)
-                    pos_d = np.zeros((K,), np.int32)
-                    for k in range(K):
-                        st = slot_state[k]
-                        if st is None:
-                            continue
-                        if active[k]:
-                            pos_t[k] = (
-                                len(st.request.tokens) + len(st.out) - 1
-                            )
-                            pos_d[k] = pos_t[k]
-                        else:
-                            pos_t[k] = st.prefill_pos
-                            pos_d[k] = st.draft_pos
-                    logits, toks_v, tokens_c, cache, draft_cache = (
-                        self._spec_round(
-                            self.params, cache, draft_cache,
-                            jnp.asarray(next_tok), jnp.asarray(pos_t),
-                            jnp.asarray(pos_d), prog,
-                            self.draft_programmed, jnp.asarray(mask),
-                            t_arg, jnp.asarray(keys_d),
-                            jnp.asarray(keys_v), temps, tks_a, tps_a,
-                        )
-                    )
-                    decode_steps += 1
-                    occupancy += int(mask.sum())
-                    toks_v_np = np.asarray(toks_v)
-                    tokens_c = np.asarray(tokens_c)
-                    l_np = (
-                        np.asarray(logits) if self.collect_logits
-                        else None
-                    )
-                    for k in range(K):
-                        if not mask[k]:
-                            continue
-                        st = slot_state[k]
-                        st.decode_steps += 1
-                        fin = False
-                        for c in range(C):
-                            # the target's token at this position is
-                            # ALWAYS what gets emitted (greedy argmax or
-                            # the per-emission-key draw on the target's
-                            # logits): acceptance only decides how many
-                            # columns of this round are usable
-                            tok_t = int(toks_v_np[k, c])
-                            row = l_np[k, c] if l_np is not None else None
-                            generated += 1
-                            fin = self._emit(st, tok_t, row)
-                            if fin or c == C - 1:
-                                break
-                            # the draft for the NEXT column is examined:
-                            # column c+1's logits are valid iff its
-                            # input token (the draft) equals tok_t
-                            st.tokens_drafted += 1
-                            if int(tokens_c[k, c + 1]) != tok_t:
-                                break
-                            st.tokens_accepted += 1
-                        if fin:
-                            results[st.request.rid] = self._result(
-                                st, now()
-                            )
-                            self._blocks.release(st.plan)
-                            slot_state[k] = None
-                            active[k] = False
-                        else:
-                            next_tok[k] = int(st.out[-1])
-            total_chunks += chunks_run
-            # trace every iteration — including idle deferral re-checks
-            # below, so sum(t["deferred"]) == report.admission_deferrals
-            if trace is not None:
-                trace.append({
+                        decode_steps += 1
+                        occupancy += int(mask.sum())
+                        with span("serve.sync", what="spec"):
+                            toks_v_np = np.asarray(toks_v)
+                            tokens_c = np.asarray(tokens_c)
+                        l_np = None
+                        if self.collect_logits:
+                            with span("serve.sync", what="logits"):
+                                l_np = np.asarray(logits)
+                        with span("serve.emit"):
+                            for k in range(K):
+                                if not mask[k]:
+                                    continue
+                                st = slot_state[k]
+                                st.decode_steps += 1
+                                fin = False
+                                for c in range(C):
+                                    # the target's token at this position
+                                    # is ALWAYS what gets emitted (greedy
+                                    # argmax or the per-emission-key draw
+                                    # on the target's logits): acceptance
+                                    # only decides how many columns of
+                                    # this round are usable
+                                    tok_t = int(toks_v_np[k, c])
+                                    row = (
+                                        l_np[k, c] if l_np is not None
+                                        else None
+                                    )
+                                    generated += 1
+                                    fin = self._emit(st, tok_t, row)
+                                    if fin or c == C - 1:
+                                        break
+                                    # the draft for the NEXT column is
+                                    # examined: column c+1's logits are
+                                    # valid iff its input token (the
+                                    # draft) equals tok_t
+                                    st.tokens_drafted += 1
+                                    if int(tokens_c[k, c + 1]) != tok_t:
+                                        break
+                                    st.tokens_accepted += 1
+                                if fin:
+                                    results[st.request.rid] = self._result(
+                                        st, now()
+                                    )
+                                    self._blocks.release(st.plan)
+                                    slot_state[k] = None
+                                    active[k] = False
+                                else:
+                                    next_tok[k] = int(st.out[-1])
+                total_chunks += chunks_run
+                # one record of every iteration — including idle deferral
+                # re-checks below, so sum(t["deferred"]) ==
+                # report.admission_deferrals — for the iteration's span and
+                # the collected trace alike
+                record = {
                     "chunks": chunks_run,
                     "decoded": decoded,
                     "admitted": admitted_now,
                     "deferred": queue.deferrals - def_before,
-                })
-            if decoded == 0 and chunks_run == 0 and draft_chunks == 0:
-                if len(results) == len(requests):
-                    break
-                if queue.has_ready(now()):
-                    continue  # pool-starved; a retirement frees blocks
-                nxt = queue.next_arrival()
-                if nxt is None:  # pragma: no cover - defensive
-                    break
-                wait = nxt - now()
-                if wait > 0:
-                    time.sleep(min(wait, 0.05))
+                }
+                it.set_metadata(**record)
+                if trace is not None:
+                    trace.append(record)
+                if decoded == 0 and chunks_run == 0 and draft_chunks == 0:
+                    if len(results) == len(requests):
+                        break
+                    if queue.has_ready(now()):
+                        continue  # pool-starved; a retirement frees blocks
+                    nxt = queue.next_arrival()
+                    if nxt is None:  # pragma: no cover - defensive
+                        break
+                    wait = nxt - now()
+                    if wait > 0:
+                        with span("serve.wait"):
+                            time.sleep(min(wait, 0.05))
 
         wall = now()
         ordered = [results[r.rid] for r in requests]

@@ -236,6 +236,8 @@ def make_chunk_prefill(
     policy = policy or DIGITAL
     rng = jax.random.PRNGKey(0)  # static programming noise for serving
 
+    # jitted, this is the module ``jit_chunk_fn``: the benchmark reads
+    # that name in the device trace
     def chunk_fn(
         params, cache, tokens, slot, start, n_valid, final,
         programmed=None, t_now=None,

@@ -10,8 +10,13 @@ not a chip run.
 The topology is described inside a module fixture, never at import:
 only one process may hold the TPU compiler library at a time, and a test
 worker that cannot describe it skips these tests from the fixture.
+
+Each compile also pins the name the kernel's operation carries, which
+the benchmark's readers look up in the device trace.
 """
 import functools
+import sys
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -24,6 +29,12 @@ from repro.kernels.paged_attention import (
     paged_decode_attention,
 )
 from repro.kernels.sliced_matmul import fused_sliced_matmul_pallas
+
+ROOT = Path(__file__).resolve().parents[1]
+if str(ROOT) not in sys.path:
+    sys.path.insert(0, str(ROOT))
+
+from bench.trace import op_name  # noqa: E402
 
 QWEN = get("qwen2-0.5b")
 INT8 = spec("int8")
@@ -63,6 +74,17 @@ def _pad(n):
     return -(-n // TILE) * TILE
 
 
+def _kernels(compiled) -> set:
+    """The names of the compiled program's Mosaic kernels, as the device
+    trace's reduction (``bench.trace.op_name``) gives them (the trace's
+    event carries the instruction without the text's ``ROOT`` mark)."""
+    return {
+        op_name(line.strip().removeprefix("ROOT "))
+        for line in compiled.as_text().splitlines()
+        if 'custom_call_target="tpu_custom_call"' in line
+    }
+
+
 @pytest.mark.parametrize(
     "k,n",
     [(QWEN.d_model, QWEN.d_ff), (QWEN.d_ff, QWEN.d_model)],
@@ -81,6 +103,7 @@ def test_fused_gemm_compiles(one_chip, k, n):
         _shape(one_chip, (kp // TILE, np_ // TILE), jnp.float32),
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    assert _kernels(compiled) == {"fused_sliced_matmul_pallas"}
 
 
 def _pool(one_chip, dtype):
@@ -104,6 +127,7 @@ def test_paged_decode_compiles(one_chip, dtype):
         _shape(one_chip, (SLOTS,), jnp.int32),
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    assert _kernels(compiled) == {"paged_decode_attention"}
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
@@ -119,3 +143,4 @@ def test_paged_chunk_compiles(one_chip, dtype):
         _shape(one_chip, (), jnp.int32),
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    assert _kernels(compiled) == {"_paged_chunk_call"}
