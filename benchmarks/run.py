@@ -1537,8 +1537,12 @@ def bench_paged_attention(quick=False):
         n_blocks = B * nb + 1
         key = jax.random.PRNGKey(max_len)
         k1, k2, k3, k4 = jax.random.split(key, 4)
-        pool_k = jax.random.normal(k1, (n_blocks, bs, KVH, hd), jnp.float32)
-        pool_v = jax.random.normal(k2, (n_blocks, bs, KVH, hd), jnp.float32)
+        # a one-layer stacked arena, attended at layer 0
+        pool_k = jax.random.normal(k1, (1, n_blocks, bs, KVH * hd),
+                                   jnp.float32)
+        pool_v = jax.random.normal(k2, (1, n_blocks, bs, KVH * hd),
+                                   jnp.float32)
+        layer = jnp.int32(0)
         bt = (
             jax.random.permutation(k3, n_blocks - 1)[: B * nb]
             .reshape(B, nb)
@@ -1548,7 +1552,8 @@ def bench_paged_attention(quick=False):
         q = jax.random.normal(k4, (B, H, hd), jnp.float32)
         gather_fn = jax.jit(
             lambda q, pk, pv, bt, pos: attention_decode(
-                q, _paged_gather(pk, bt), _paged_gather(pv, bt), pos
+                q, _paged_gather(pk, 0, bt, hd), _paged_gather(pv, 0, bt, hd),
+                pos,
             )
         )
         y_ref, us = _timed_min(
@@ -1559,7 +1564,8 @@ def bench_paged_attention(quick=False):
         gather_blocks[str(max_len)] = nb
         if max_len == 32:
             y_k, us_k = _timed_min(
-                lambda *a: paged_decode_attention(*a, interpret=True),
+                lambda q, pk, pv, bt, pos: paged_decode_attention(
+                    q, pk, pv, layer, bt, pos, interpret=True),
                 q, pool_k, pool_v, bt, pos, repeats=1,
             )
             section["decode_bitwise_vs_gather"] = float(
@@ -1573,12 +1579,12 @@ def bench_paged_attention(quick=False):
             )
             ref_c = attention_dense(
                 qc,
-                _paged_gather(pool_k, bt[:1]),
-                _paged_gather(pool_v, bt[:1]),
+                _paged_gather(pool_k, 0, bt[:1], hd),
+                _paged_gather(pool_v, 0, bt[:1], hd),
                 q_off=start,
             )
             out_c = paged_chunk_attention(
-                qc, pool_k, pool_v, bt[0], jnp.int32(start),
+                qc, pool_k, pool_v, layer, bt[0], jnp.int32(start),
                 jnp.int32(n_valid), interpret=True,
             )
             section["chunk_bitwise_vs_gather_valid"] = float(

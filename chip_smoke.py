@@ -178,21 +178,24 @@ def phase_attention(cfg, seed):
     pos = jnp.asarray(rng.integers(0, MAX_LEN, SLOTS), jnp.int32)
     start = MAX_LEN - CHUNK - 37
     interpret = kops.kernel_interpret()
+    # a stacked three-layer arena, attended at its middle layer
+    layers, layer = 3, jnp.int32(1)
     for dtype in (jnp.float32, jnp.bfloat16):
         ks = jax.random.split(jax.random.PRNGKey(seed + 1), 4)
         pool = lambda k: jax.random.normal(
-            k, (n_blocks, BLOCK, kvh, hd)
+            k, (layers, n_blocks, BLOCK, kvh * hd)
         ).astype(dtype)
         pk, pv = pool(ks[0]), pool(ks[1])
         q = jax.random.normal(ks[2], (SLOTS, h, hd)).astype(dtype)
         qc = jax.random.normal(ks[3], (1, CHUNK, h, hd)).astype(dtype)
-        out_d = paged_decode_attention(q, pk, pv, bt, pos,
+        out_d = paged_decode_attention(q, pk, pv, layer, bt, pos,
                                        interpret=interpret)
         out_c = paged_chunk_attention(
-            qc, pk, pv, bt[0], jnp.int32(start), jnp.int32(CHUNK),
+            qc, pk, pv, layer, bt[0], jnp.int32(start), jnp.int32(CHUNK),
             interpret=interpret,
         )
-        gk, gv = _paged_gather(pk, bt), _paged_gather(pv, bt)
+        gk = _paged_gather(pk, layer, bt, hd)
+        gv = _paged_gather(pv, layer, bt, hd)
         ref_d = attention_decode(q, gk, gv, pos)
         ref_c = attention_dense(qc, gk[:1], gv[:1], q_off=start)
         bound = ATTN_BOUND[jnp.dtype(dtype).name]

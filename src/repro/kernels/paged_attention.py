@@ -11,6 +11,13 @@ and every grid step past the prefix limit clamps its index map to the
 last mapped block — Mosaic elides the repeated DMA, so HBM traffic is
 O(prefix), not O(max_len).
 
+The kernels take the whole stacked arena ``(L, n_blocks, bs, KV*hd)``
+of a layer segment and the layer index, scalar-prefetched like the
+table: the index maps address ``(layer, block)`` directly, so the layer
+scan never cuts a layer's pool out of the arena (DESIGN.md §7).  The
+heads share the minor axis, which keeps the arena in the row-major
+``(bs, KV*hd)`` tiling that both the scatter and Mosaic take.
+
 Numerics contract (tests/test_paged_attention.py): the kernels are
 BITWISE equal to the dense-gather path in interpret mode.  Mapped
 blocks are copied into a full-S ``(KV, S, hd)`` VMEM scratch (unmapped
@@ -18,10 +25,10 @@ tail left zero), and the final grid step replays the math of
 ``attention_decode`` / ``attention_dense`` on that scratch as dots
 batched over KV heads, accumulated in f32 (DESIGN.md §7).  Compiled by
 Mosaic, the class against the XLA oracle is measured on the chip
-(DESIGN.md §7 table, ``chip_smoke.py``).  Tail and
-trash positions hold zeros here vs. junk in the gathered view, but both
-are masked to -1e30 before the softmax, ``exp`` underflows to exactly
-0.0, and a 0.0 probability contributes exactly 0.0 to the PV
+(DESIGN.md §7 table, ``chip_smoke.py``).  Tail and trash positions
+hold zeros here vs. junk in the gathered view, but both are masked to
+-1e30 before the softmax, ``exp`` underflows to exactly 0.0, and a 0.0
+probability contributes exactly 0.0 to the PV
 contraction either way — so the difference is value-invisible.
 
 The attention math is intentionally REPLICATED here rather than
@@ -45,14 +52,16 @@ _CHUNK_VMEM_LIMIT = 64 * 1024 * 1024
 
 
 def _copy_block(kb_ref, vb_ref, ks_ref, vs_ref, kb, bs):
-    """Copy one mapped (bs, KV, hd) pool block into the (KV, S, hd)
-    scratch, one KV head at a time: each head's rows land as a dense
-    (bs, hd) slab, so no sublane padding of the KV axis and no in-kernel
-    reshape (Mosaic tiles only the last two dims)."""
+    """Copy one mapped (bs, KV*hd) pool block into the (KV, S, hd)
+    scratch, one KV head (an hd-wide lane slice) at a time: each head's
+    rows land as a dense (bs, hd) slab, so no in-kernel reshape (Mosaic
+    tiles only the last two dims)."""
     off = pl.multiple_of(kb * bs, bs)
+    hd = ks_ref.shape[-1]
     for kh in range(ks_ref.shape[0]):
-        ks_ref[kh, pl.ds(off, bs), :] = kb_ref[0, :, kh, :]
-        vs_ref[kh, pl.ds(off, bs), :] = vb_ref[0, :, kh, :]
+        lanes = pl.ds(kh * hd, hd)
+        ks_ref[kh, pl.ds(off, bs), :] = kb_ref[0, 0, :, lanes]
+        vs_ref[kh, pl.ds(off, bs), :] = vb_ref[0, 0, :, lanes]
 
 
 def _scores(q, k, interpret):
@@ -99,10 +108,11 @@ def _decode_kernel(
     # scalar prefetch
     bt_ref,  # (B, nb) int32 block tables
     pos_ref,  # (B,) int32 current token index per slot
+    layer_ref,  # (1,) int32 the layer's index in the stacked arena
     # inputs
     q_ref,  # (1, KV, G, dh) this slot's query, grouped by KV head
-    kb_ref,  # (1, bs, KV, hd) the mapped K pool block for this step
-    vb_ref,  # (1, bs, KV, hd)
+    kb_ref,  # (1, 1, bs, KV*hd) the mapped K pool block for this step
+    vb_ref,  # (1, 1, bs, KV*hd)
     # outputs
     out_ref,  # (1, KV, G, dh) pool dtype (attention_decode returns v.dtype)
     # scratch
@@ -146,9 +156,10 @@ def _decode_kernel(
         out_ref[0] = _pv(p / l, vs_ref[...])
 
 
-def _scratch(pool, nb):
-    kvh, hd = pool.shape[2:]
-    return pltpu.VMEM((kvh, nb * pool.shape[1], hd), pool.dtype)
+def _scratch(pool, nb, hd):
+    return pltpu.VMEM(
+        (pool.shape[3] // hd, nb * pool.shape[2], hd), pool.dtype
+    )
 
 
 # jitted under its own name, the kernel's operation in the compiled
@@ -158,8 +169,9 @@ def _scratch(pool, nb):
 )
 def paged_decode_attention(
     q: jax.Array,  # (B, H, dh)
-    pool_k: jax.Array,  # (n_blocks, bs, KV, hd)
-    pool_v: jax.Array,  # (n_blocks, bs, KV, hd)
+    pool_k: jax.Array,  # (L, n_blocks, bs, KV*hd) stacked arena
+    pool_v: jax.Array,  # (L, n_blocks, bs, KV*hd)
+    layer: jax.Array,  # () int32 which layer of the arena to attend
     block_tables: jax.Array,  # (B, nb) int32
     pos: jax.Array,  # (B,) int32
     *,
@@ -167,34 +179,36 @@ def paged_decode_attention(
     interpret: bool = False,
 ) -> jax.Array:
     """One-token paged attention: bitwise ``attention_decode`` over the
-    gathered logical view, reading only the mapped prefix blocks."""
-    bsz, nb = pool_k.shape[1], block_tables.shape[1]
+    gathered logical view of layer ``layer``, reading only the mapped
+    prefix blocks."""
+    bsz, nb = pool_k.shape[2], block_tables.shape[1]
     b, h, dh = q.shape
-    kvh = pool_k.shape[2]
+    kvh = pool_k.shape[3] // dh
     qg = q.reshape(b, kvh, h // kvh, dh)
+    blk = (1, 1, bsz, pool_k.shape[3])
 
-    def q_map(i, kb, bt, p):
+    def q_map(i, kb, bt, p, lyr):
         return (i, 0, 0, 0)
 
-    def kv_map(i, kb, bt, p):
+    def kv_map(i, kb, bt, p, lyr):
         # clamp beyond-limit steps to the last mapped block: the index
         # map then repeats, Mosaic elides the DMA, and pl.when skips the
         # copy — beyond-prefix blocks cost no HBM traffic.
-        return (bt[i, jnp.minimum(kb, p[i] // bsz)], 0, 0, 0)
+        return (lyr[0], bt[i, jnp.minimum(kb, p[i] // bsz)], 0, 0)
 
     kernel = functools.partial(
         _decode_kernel, bs=bsz, nb=nb, window=window, interpret=interpret
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(b, nb),
         in_specs=[
             pl.BlockSpec((1,) + qg.shape[1:], q_map),
-            pl.BlockSpec((1, bsz) + pool_k.shape[2:], kv_map),
-            pl.BlockSpec((1, bsz) + pool_v.shape[2:], kv_map),
+            pl.BlockSpec(blk, kv_map),
+            pl.BlockSpec(blk, kv_map),
         ],
         out_specs=pl.BlockSpec((1,) + qg.shape[1:], q_map),
-        scratch_shapes=[_scratch(pool_k, nb), _scratch(pool_v, nb)],
+        scratch_shapes=[_scratch(pool_k, nb, dh), _scratch(pool_v, nb, dh)],
     )
     out = pl.pallas_call(
         kernel,
@@ -204,8 +218,8 @@ def paged_decode_attention(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,
-    )(block_tables.astype(jnp.int32), pos.astype(jnp.int32), qg, pool_k,
-      pool_v)
+    )(block_tables.astype(jnp.int32), pos.astype(jnp.int32),
+      jnp.asarray(layer, jnp.int32)[None], qg, pool_k, pool_v)
     return out.reshape(b, h, dh)
 
 
@@ -214,10 +228,11 @@ def _chunk_kernel(
     bt_ref,  # (nb,) int32 this slot's block table
     lim_ref,  # (1,) int32 last logical block the chunk touches
     start_ref,  # (1,) int32 logical position of the chunk's first token
+    layer_ref,  # (1,) int32 the layer's index in the stacked arena
     # inputs
     q_ref,  # (G, KV, C, dh) chunk queries, head-major
-    kb_ref,  # (1, bs, KV, hd)
-    vb_ref,  # (1, bs, KV, hd)
+    kb_ref,  # (1, 1, bs, KV*hd)
+    vb_ref,  # (1, 1, bs, KV*hd)
     # outputs
     out_ref,  # (G, KV, C, dh)
     # scratch
@@ -269,8 +284,9 @@ def _chunk_kernel(
 
 def paged_chunk_attention(
     q: jax.Array,  # (1, C, H, dh) chunk queries (batch of one slot)
-    pool_k: jax.Array,  # (n_blocks, bs, KV, hd)
+    pool_k: jax.Array,  # (L, n_blocks, bs, KV*hd) stacked arena
     pool_v: jax.Array,
+    layer: jax.Array,  # () int32 which layer of the arena to attend
     bt_row: jax.Array,  # (nb,) int32
     start: jax.Array,  # scalar int32 logical position of first token
     n_valid: jax.Array,  # scalar int32 valid tokens in the chunk
@@ -279,10 +295,10 @@ def paged_chunk_attention(
     interpret: bool = False,
 ) -> jax.Array:
     """Chunk-prefill paged attention: bitwise ``attention_dense`` over
-    the gathered logical view, reading only blocks 0..ceil((start+C)/bs)
-    — chunk cost is O(prefix), not O(max_len)."""
+    the gathered logical view of layer ``layer``, reading only blocks
+    0..ceil((start+C)/bs) — chunk cost is O(prefix), not O(max_len)."""
     _, c, h, dh = q.shape
-    bsz, nb = pool_k.shape[1], bt_row.shape[0]
+    bsz, nb = pool_k.shape[2], bt_row.shape[0]
     # last block the chunk's causal view can reach: its final VALID
     # token sits at logical position start + n_valid - 1.  (Pad queries
     # past n_valid attend over a zero tail here vs junk on the dense
@@ -296,6 +312,7 @@ def paged_chunk_attention(
         bt_row.astype(jnp.int32),
         lim[None],
         jnp.asarray(start, jnp.int32)[None],
+        jnp.asarray(layer, jnp.int32)[None],
         window=window,
         interpret=interpret,
     )[None]
@@ -305,33 +322,34 @@ def paged_chunk_attention(
 # program is named ``_paged_chunk_call``: the benchmark reads it
 @functools.partial(jax.jit, static_argnames=("window", "interpret"))
 def _paged_chunk_call(
-    q, pool_k, pool_v, bt_row, lim, start, *, window, interpret
+    q, pool_k, pool_v, bt_row, lim, start, layer, *, window, interpret
 ):
     c, h, dh = q.shape
-    bsz, nb = pool_k.shape[1], bt_row.shape[0]
-    kvh = pool_k.shape[2]
+    bsz, nb = pool_k.shape[2], bt_row.shape[0]
+    kvh = pool_k.shape[3] // dh
+    blk = (1, 1, bsz, pool_k.shape[3])
     # head-major (G, KV, C, dh): each query head is a dense (C, dh) slab
     qh = q.reshape(c, kvh, h // kvh, dh).transpose(2, 1, 0, 3)
 
-    def q_map(kb, bt, lim, st):
+    def q_map(kb, bt, lim, st, lyr):
         return (0, 0, 0, 0)
 
-    def kv_map(kb, bt, lim, st):
-        return (bt[jnp.minimum(kb, lim[0])], 0, 0, 0)
+    def kv_map(kb, bt, lim, st, lyr):
+        return (lyr[0], bt[jnp.minimum(kb, lim[0])], 0, 0)
 
     kernel = functools.partial(
         _chunk_kernel, bs=bsz, nb=nb, window=window, interpret=interpret
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(nb,),
         in_specs=[
             pl.BlockSpec(qh.shape, q_map),
-            pl.BlockSpec((1, bsz) + pool_k.shape[2:], kv_map),
-            pl.BlockSpec((1, bsz) + pool_v.shape[2:], kv_map),
+            pl.BlockSpec(blk, kv_map),
+            pl.BlockSpec(blk, kv_map),
         ],
         out_specs=pl.BlockSpec(qh.shape, q_map),
-        scratch_shapes=[_scratch(pool_k, nb), _scratch(pool_v, nb)],
+        scratch_shapes=[_scratch(pool_k, nb, dh), _scratch(pool_v, nb, dh)],
     )
     out = pl.pallas_call(
         kernel,
@@ -346,5 +364,5 @@ def _paged_chunk_call(
             vmem_limit_bytes=_CHUNK_VMEM_LIMIT,
         ),
         interpret=interpret,
-    )(bt_row, lim, start, qh, pool_k, pool_v)
+    )(bt_row, lim, start, layer, qh, pool_k, pool_v)
     return out.transpose(2, 1, 0, 3).reshape(c, h, dh)

@@ -12,9 +12,13 @@ Execution paths:
   flash-decode path used by decode_32k / long_500k where the KV sequence
   is sharded over the ``model`` mesh axis.
 * paged variants (DESIGN.md §7) — ``decode_attention_block`` with
-  ``block_tables`` and ``chunk_attention_block`` address a *block pool*
-  (``(n_blocks, block_size, KV, hd)``, shared by every serving slot)
-  through per-slot block tables instead of a dense per-slot cache row.
+  ``block_tables``, ``chunk_attention_block`` and
+  ``verify_attention_block`` address a *block pool* shared by every
+  serving slot through per-slot block tables instead of a dense
+  per-slot cache row.  They take a layer segment's whole stacked arena
+  ``(L, n_blocks, block_size, KV*hd)`` plus the layer index, write
+  their rows into it with one scatter at ``(layer, block, offset)`` and
+  return it: the layer scan carries the arena and updates it in place.
   Two implementations, selected by
   ``repro.kernels.ops.resolve_attention_backend()``:
 
@@ -295,37 +299,39 @@ def init_kv_cache(cfg, batch, max_len, dtype=jnp.bfloat16, layers=None):
 # ---------------------------------------------------------------------------
 
 
-def _paged_gather(pool, block_tables):
-    """Materialise a slot-major logical view of the block pool.
+def _paged_gather(pool, layer, block_tables, head_dim):
+    """Materialise a slot-major logical view of one layer of the arena.
 
-    pool: (n_blocks, bs, KV, hd); block_tables: (B, nb) physical block
-    ids → (B, nb*bs, KV, hd).  The gather is a pure data movement: the
-    returned buffer holds, at logical position ``p`` of slot ``b``,
-    exactly the bytes a dense ``(B, max_len)`` arena would hold there
-    (unallocated tail blocks alias the trash block; every read of those
-    positions is masked before the softmax), so downstream attention is
-    bitwise identical to the dense layout.
+    pool: (L, n_blocks, bs, KV*hd) stacked arena; layer: its index;
+    block_tables: (B, nb) physical block ids → (B, nb*bs, KV, hd).  The
+    gather is a pure data movement: the returned buffer holds, at
+    logical position ``p`` of slot ``b``, exactly the bytes a dense
+    ``(B, max_len)`` arena would hold there (unallocated tail blocks
+    alias the trash block; every read of those positions is masked
+    before the softmax), so downstream attention is bitwise identical
+    to the dense layout.
     """
     b, nb = block_tables.shape
-    g = pool[block_tables]  # (B, nb, bs, KV, hd)
-    return g.reshape(b, nb * pool.shape[1], *pool.shape[2:])
+    g = pool[layer, block_tables]  # (B, nb, bs, KV*hd)
+    return g.reshape(b, nb * pool.shape[2], -1, head_dim)
 
 
-def _paged_token_write(pool, block_tables, pos, val, active):
-    """Scatter one token's K or V into the pool at logical ``pos``.
+def _paged_token_write(pool, layer, block_tables, pos, val, active):
+    """Scatter one token's K or V into layer ``layer`` of the arena at
+    logical ``pos``.
 
     val: (B, KV, hd) already in pool dtype.  Rows with ``active`` False
     (idle / still-prefilling serving lanes) are routed to the trash
     block instead — an inactive lane can never mutate live KV, even when
     its stale block table aliases blocks that were freed and re-allocated
     to another request (the no-leak half of the paged contract)."""
-    bsz = pool.shape[1]
+    bsz = pool.shape[2]
     blk = jnp.take_along_axis(
         block_tables, (pos // bsz)[:, None], axis=1
     )[:, 0]
     if active is not None:
         blk = jnp.where(active, blk, TRASH_BLOCK)
-    return pool.at[blk, pos % bsz].set(val)
+    return pool.at[layer, blk, pos % bsz].set(val.reshape(val.shape[0], -1))
 
 
 def attention_verify(q, k_cache, v_cache, pos, *, window=0):
@@ -376,8 +382,9 @@ def attention_verify(q, k_cache, v_cache, pos, *, window=0):
     return out.reshape(b, c, h, dh)
 
 
-def _paged_multi_write(pool, block_tables, pos, vals, active):
-    """Scatter C consecutive tokens' K or V per slot into the pool.
+def _paged_multi_write(pool, layer, block_tables, pos, vals, active):
+    """Scatter C consecutive tokens' K or V per slot into layer
+    ``layer`` of the arena.
 
     vals: (B, C, KV, hd) in pool dtype; token ``c`` of slot ``b`` lands
     at logical position ``pos[b] + c``.  Rows with ``active`` False and
@@ -388,7 +395,7 @@ def _paged_multi_write(pool, block_tables, pos, vals, active):
     already owns — and only at positions >= ``pos`` (its own current
     frontier), so no live KV is overwritten."""
     b, c = vals.shape[0], vals.shape[1]
-    bsz = pool.shape[1]
+    bsz = pool.shape[2]
     nb = block_tables.shape[1]
     lp = pos[:, None] + jnp.arange(c)[None, :]  # (B, C) logical positions
     blk = jnp.take_along_axis(
@@ -398,7 +405,7 @@ def _paged_multi_write(pool, block_tables, pos, vals, active):
     if active is not None:
         ok &= active[:, None]
     blk = jnp.where(ok, blk, TRASH_BLOCK)
-    return pool.at[blk, lp % bsz].set(vals)
+    return pool.at[layer, blk, lp % bsz].set(vals.reshape(b, c, -1))
 
 
 def attention_block(
@@ -466,7 +473,7 @@ def attention_block(
 
 def decode_attention_block(
     p, x1, cfg, *, policy, rng, cache_k, cache_v, pos, name, cross=False,
-    prepared=None, active=None, block_tables=None,
+    prepared=None, active=None, block_tables=None, layer=None,
 ):
     """One-token attention block against the cache (dense or paged).
 
@@ -475,11 +482,13 @@ def decode_attention_block(
 
     * dense (``block_tables=None``) — cache_k/v: (B, S, KV, dh) per-slot
       rows; returns (y, new_cache_k, new_cache_v).
-    * paged — cache_k/v are the shared block POOL
-      ``(n_blocks, bs, KV, dh)`` and ``block_tables`` (B, nb) maps each
-      slot's logical blocks to physical ones.  The pool is gathered into
-      logical order before the attention math, so logits are bitwise
-      identical to the dense layout for any block placement.
+    * paged — cache_k/v are a segment's stacked block POOL
+      ``(L, n_blocks, bs, KV*dh)``, ``layer`` this layer's index in it,
+      and ``block_tables`` (B, nb) maps each slot's logical blocks to
+      physical ones.  The layer's pool is read in logical order by the
+      attention math, so logits are bitwise identical to the dense
+      layout for any block placement; the returned pools are the whole
+      arena with this layer's row written.
 
     ``active``: optional (B,) bool — rows where it is False must not
     mutate live KV: on the dense path they re-write their OLD cache
@@ -512,10 +521,10 @@ def decode_attention_block(
         v1c = v1.astype(cache_v.dtype)
         if block_tables is not None:
             cache_k = _paged_token_write(
-                cache_k, block_tables, pos, k1c, active
+                cache_k, layer, block_tables, pos, k1c, active
             )
             cache_v = _paged_token_write(
-                cache_v, block_tables, pos, v1c, active
+                cache_v, layer, block_tables, pos, v1c, active
             )
         else:
             if active is not None:
@@ -546,12 +555,12 @@ def decode_attention_block(
                 paged_decode_attention, window=window,
                 interpret=_kops.kernel_interpret(),
             ),
-            q, cache_k, cache_v, block_tables, pos,
+            q, cache_k, cache_v, layer, block_tables, pos,
         )
     else:
         if block_tables is not None:
-            att_k = _paged_gather(cache_k, block_tables)
-            att_v = _paged_gather(cache_v, block_tables)
+            att_k = _paged_gather(cache_k, layer, block_tables, hd)
+            att_v = _paged_gather(cache_v, layer, block_tables, hd)
         else:
             att_k, att_v = cache_k, cache_v
         out = attention_decode(q, att_k, att_v, pos, window=window)
@@ -563,18 +572,20 @@ def decode_attention_block(
 
 
 def chunk_attention_block(
-    p, x, cfg, *, policy, rng, pool_k, pool_v, bt_row, start, n_valid,
-    positions, name, prepared=None,
+    p, x, cfg, *, policy, rng, pool_k, pool_v, layer, bt_row, start,
+    n_valid, positions, name, prepared=None,
 ):
     """Attention block for one CHUNK of a prompt against the paged pool
     (chunked prefill, serve/batching.py, DESIGN.md §7).
 
     x: (1, C, d) chunk activations, right-padded past ``n_valid``;
     ``start``: logical position of the chunk's first token; ``bt_row``:
-    (nb,) this slot's block table.  The chunk's K/V are written into the
-    slot's blocks first (pad tokens route to the trash block), then the
-    queries attend over the GATHERED logical view — prefix written by
-    earlier chunks plus this chunk — under the causal ``ki <= qi`` mask.
+    (nb,) this slot's block table; ``pool_k``/``pool_v``: the segment's
+    stacked arena, ``layer`` this layer's index in it.  The chunk's K/V
+    are written into the slot's blocks first (pad tokens route to the
+    trash block), then the queries attend over the GATHERED logical
+    view — prefix written by earlier chunks plus this chunk — under the
+    causal ``ki <= qi`` mask.
 
     Numerics contract: per-token math is identical to single-shot
     prefill — same projections, same RoPE positions, same masked-softmax
@@ -607,14 +618,16 @@ def chunk_attention_block(
     v = constrain(v, "batch", "seq", "kv_heads", "head_dim")
     # scatter the chunk's K/V into the slot's blocks; pad tokens (their
     # logical position is >= start + n_valid) go to the trash block
-    bsz = pool_k.shape[1]
+    bsz = pool_k.shape[2]
     lp = start + jnp.arange(c)
     valid = jnp.arange(c) < n_valid
     blk = jnp.where(valid, bt_row[jnp.clip(lp // bsz, 0, bt_row.shape[0] - 1)],
                     TRASH_BLOCK)
     off = lp % bsz
-    pool_k = pool_k.at[blk, off].set(k.astype(pool_k.dtype)[0])
-    pool_v = pool_v.at[blk, off].set(v.astype(pool_v.dtype)[0])
+    pool_k = pool_k.at[layer, blk, off].set(
+        k.astype(pool_k.dtype)[0].reshape(c, -1))
+    pool_v = pool_v.at[layer, blk, off].set(
+        v.astype(pool_v.dtype)[0].reshape(c, -1))
     # attend over the gathered logical view (prefix + this chunk); keys
     # past each query's position — including every pad position — are
     # masked to -inf by the causal mask inside attention_dense
@@ -628,11 +641,11 @@ def chunk_attention_block(
                 paged_chunk_attention, window=cfg.swa_window,
                 interpret=_kops.kernel_interpret(),
             ),
-            q, pool_k, pool_v, bt_row, start, n_valid,
+            q, pool_k, pool_v, layer, bt_row, start, n_valid,
         )
     else:
-        g_k = _paged_gather(pool_k, bt_row[None])
-        g_v = _paged_gather(pool_v, bt_row[None])
+        g_k = _paged_gather(pool_k, layer, bt_row[None], hd)
+        g_v = _paged_gather(pool_v, layer, bt_row[None], hd)
         out = attention_dense(q, g_k, g_v, q_off=start, window=cfg.swa_window)
     out = constrain(out, "batch", "seq", "heads", "head_dim")
     y = dense(
@@ -643,17 +656,19 @@ def chunk_attention_block(
 
 
 def verify_attention_block(
-    p, x, cfg, *, policy, rng, pool_k, pool_v, block_tables, pos, name,
-    prepared=None, active=None,
+    p, x, cfg, *, policy, rng, pool_k, pool_v, layer, block_tables, pos,
+    name, prepared=None, active=None,
 ):
     """Attention block for one SPECULATIVE VERIFY chunk against the
     paged pool (serve/batching.py, DESIGN.md §7).
 
     x: (B, C, d) — per slot, the activations of the last emitted token
     followed by C-1 draft proposals, at logical positions
-    ``pos[b] .. pos[b]+C-1``.  All C positions' K/V are written into
-    the slot's already-allocated blocks FIRST (inactive lanes and
-    out-of-capacity positions route to the trash block), then each
+    ``pos[b] .. pos[b]+C-1``; ``pool_k``/``pool_v``: the segment's
+    stacked arena, ``layer`` this layer's index in it.  All C
+    positions' K/V are written into the slot's already-allocated blocks
+    FIRST (inactive lanes and out-of-capacity positions route to the
+    trash block), then each
     position attends over the gathered logical view under the
     ``ki <= pos + c`` causal mask.
 
@@ -693,13 +708,13 @@ def verify_attention_block(
     k = constrain(k, "batch", "seq", "kv_heads", "head_dim")
     v = constrain(v, "batch", "seq", "kv_heads", "head_dim")
     pool_k = _paged_multi_write(
-        pool_k, block_tables, pos, k.astype(pool_k.dtype), active
+        pool_k, layer, block_tables, pos, k.astype(pool_k.dtype), active
     )
     pool_v = _paged_multi_write(
-        pool_v, block_tables, pos, v.astype(pool_v.dtype), active
+        pool_v, layer, block_tables, pos, v.astype(pool_v.dtype), active
     )
-    att_k = _paged_gather(pool_k, block_tables)
-    att_v = _paged_gather(pool_v, block_tables)
+    att_k = _paged_gather(pool_k, layer, block_tables, hd)
+    att_v = _paged_gather(pool_v, layer, block_tables, hd)
     out = attention_verify(q, att_k, att_v, pos, window=cfg.swa_window)
     y = dense(
         p["o_proj"], out.reshape(b, c, nh * hd), name=f"{name}.o",

@@ -336,14 +336,21 @@ def init_paged_cache(
     """Allocate the PAGED serving cache (DESIGN.md §7).
 
     Instead of a dense ``(slots, max_len)`` KV row per lane, every
-    attention layer owns one block POOL ``(n_blocks, block_size, KV, hd)``
+    attention layer owns one block POOL ``(n_blocks, block_size, KV*hd)``
     shared by all slots, addressed through per-slot ``block_tables``
-    ``(slots, ceil(max_len/block_size))``.  Physical block 0 is the
-    reserved trash block (never allocated): table entries initialised to
-    it are "unallocated", pad/idle writes are routed to it, and every
-    read of it is masked before the softmax — so the pool can be sized
-    to the live working set (``n_blocks`` < slots*blocks_per_slot) and
-    freed blocks can be re-used across requests without KV leakage.
+    ``(slots, ceil(max_len/block_size))``; a segment stacks its layers'
+    pools into one ``(steps, n_blocks, block_size, KV*hd)`` arena per K
+    and V side, which the layer scan updates in place.  The heads share
+    the minor axis so a block is a lane-dense ``(block_size, KV*hd)``
+    tile: the layout the chip keeps the arena in is the one the row
+    scatter and the paged kernels take, and no step relayouts it.
+
+    Physical block 0 is the reserved trash block (never allocated):
+    table entries initialised to it are "unallocated", pad/idle writes
+    are routed to it, and every read of it is masked before the
+    softmax — so the pool can be sized to the live working set
+    (``n_blocks`` < slots*blocks_per_slot) and freed blocks can be
+    re-used across requests without KV leakage.
 
     Attention-only families (the serving loop enforces this): SSM /
     hybrid state is recurrent, not positional, so it has nothing to
@@ -365,8 +372,8 @@ def init_paged_cache(
     }
     for si, (start, steps, tmpl) in enumerate(segments(cfg)):
         cache["blocks"][f"seg{si}"] = {
-            "k": jnp.zeros((steps, n_blocks, block_size, kvh, hd), dtype),
-            "v": jnp.zeros((steps, n_blocks, block_size, kvh, hd), dtype),
+            "k": jnp.zeros((steps, n_blocks, block_size, kvh * hd), dtype),
+            "v": jnp.zeros((steps, n_blocks, block_size, kvh * hd), dtype),
         }
     return cache
 
@@ -379,7 +386,7 @@ def copy_paged_block(cache: dict, src, dst):
     When two requests share prefix blocks (refcount > 1) the block
     holding the first position a lane will WRITE must be cloned before
     that write: the sharer keeps reading ``src`` while the writer's
-    block table points at ``dst``.  One ``(steps, block_size, kvh, hd)``
+    block table points at ``dst``.  One ``(steps, block_size, kvh*hd)``
     row moves per layer segment and K/V side; ``src``/``dst`` are traced
     scalars so the serve loop jits this once (donating the arena) for
     any block pair.  Block tables and ``pos`` are untouched — the caller
@@ -413,6 +420,34 @@ def _seg_cache(cfg, tmpl, steps, batch, max_len, dtype):
     )
 
 
+def _scan_layers(layer_fn, x, seg_p, prog_seg, seg_c, *, carried):
+    """Run ``layer_fn(x, p_l, prog_l, state, idx) -> (x, state)`` over a
+    segment's layers.
+
+    ``carried`` (the paged arena): ``seg_c`` is the whole stacked
+    ``(L, n_blocks, ...)`` arena, handed to every layer with its index
+    and threaded through the scan CARRY, so each layer's scatter updates
+    the one buffer in place and no layer's pool is cut out or written
+    back.  Otherwise (the dense cache, recurrent state): each layer gets
+    its own slice of ``seg_c`` as a scan input and returns it as an
+    output."""
+    idx = jnp.arange(jax.tree_util.tree_leaves(seg_p)[0].shape[0])
+    if carried:
+        def step(carry, inp):
+            x, state = carry
+            p_l, prog_l, i = inp
+            return layer_fn(x, p_l, prog_l, state, i), None
+
+        (x, seg_c), _ = lax.scan(step, (x, seg_c), (seg_p, prog_seg, idx))
+        return x, seg_c
+
+    def step(x, inp):
+        p_l, prog_l, c_l, i = inp
+        return layer_fn(x, p_l, prog_l, c_l, i)
+
+    return lax.scan(step, x, (seg_p, prog_seg, seg_c, idx))
+
+
 def decode_step(
     params,
     cfg: ArchConfig,
@@ -438,9 +473,10 @@ def decode_step(
 
     Cache layouts: the dense ``init_cache`` pytree, or the paged
     ``init_paged_cache`` pytree (detected by its ``block_tables`` leaf)
-    — blocks are gathered into logical order before the attention math,
-    so for the same stored KV the two layouts produce bitwise-identical
-    logits on the fast path."""
+    — blocks are read in logical order by the attention math, so for
+    the same stored KV the two layouts produce bitwise-identical logits
+    on the fast path.  The paged arena rides the layer scan's carry and
+    is updated in place (``_scan_layers``)."""
     rng = jax.random.PRNGKey(0) if rng is None else rng
     if cfg.encoder is not None:
         return _encdec_decode(
@@ -457,25 +493,21 @@ def decode_step(
         new_cache["block_tables"] = block_tables
     prog_blocks = pget(programmed, "blocks")
     for si, (start, steps, tmpl) in enumerate(segments(cfg)):
-        seg_p = params["blocks"][f"seg{si}"]
-        seg_c = cache["blocks"][f"seg{si}"]
-        prog_seg = pget(prog_blocks, f"seg{si}")
         rng_s = jax.random.fold_in(rng, si)
 
-        def step(x1, inp):
-            p_l, prog_l, c_l, idx = inp
-            rng_l = jax.random.fold_in(rng_s, idx)
-            x1, st = block_decode(
-                p_l, x1, cfg, tmpl, policy=policy, rng=rng_l, pos=pos,
-                state=c_l, prepared=prog_l, active=active,
-                block_tables=block_tables,
+        def layer(x1, p_l, prog_l, state, idx):
+            return block_decode(
+                p_l, x1, cfg, tmpl, policy=policy,
+                rng=jax.random.fold_in(rng_s, idx), pos=pos, state=state,
+                prepared=prog_l, active=active, block_tables=block_tables,
+                layer=idx,
             )
-            return x1, st
 
-        x1, new_states = lax.scan(
-            step, x1, (seg_p, prog_seg, seg_c, jnp.arange(steps))
+        x1, new_cache["blocks"][f"seg{si}"] = _scan_layers(
+            layer, x1, params["blocks"][f"seg{si}"],
+            pget(prog_blocks, f"seg{si}"), cache["blocks"][f"seg{si}"],
+            carried=block_tables is not None,
         )
-        new_cache["blocks"][f"seg{si}"] = new_states
     x1 = norm(x1, params["final_norm"], cfg.norm)
     logits = dense(
         params["lm_head"], x1, name="lm_head", policy=policy, rng=rng,
@@ -537,25 +569,21 @@ def decode_verify_step(
     new_cache = {"pos": pos, "block_tables": block_tables, "blocks": {}}
     prog_blocks = pget(programmed, "blocks")
     for si, (start, steps, tmpl) in enumerate(segments(cfg)):
-        seg_p = params["blocks"][f"seg{si}"]
-        seg_c = cache["blocks"][f"seg{si}"]
-        prog_seg = pget(prog_blocks, f"seg{si}")
         rng_s = jax.random.fold_in(rng, si)
 
-        def step(x, inp):
-            p_l, prog_l, c_l, idx = inp
-            rng_l = jax.random.fold_in(rng_s, idx)
-            x, st = block_verify(
-                p_l, x, cfg, tmpl, policy=policy, rng=rng_l, pos=pos,
-                state=c_l, block_tables=block_tables, prepared=prog_l,
+        def layer(x, p_l, prog_l, state, idx):
+            return block_verify(
+                p_l, x, cfg, tmpl, policy=policy,
+                rng=jax.random.fold_in(rng_s, idx), pos=pos, state=state,
+                layer=idx, block_tables=block_tables, prepared=prog_l,
                 active=active,
             )
-            return x, st
 
-        x, new_states = lax.scan(
-            step, x, (seg_p, prog_seg, seg_c, jnp.arange(steps))
+        x, new_cache["blocks"][f"seg{si}"] = _scan_layers(
+            layer, x, params["blocks"][f"seg{si}"],
+            pget(prog_blocks, f"seg{si}"), cache["blocks"][f"seg{si}"],
+            carried=True,
         )
-        new_cache["blocks"][f"seg{si}"] = new_states
     x = norm(x, params["final_norm"], cfg.norm)
     logits = dense(
         params["lm_head"], x, name="lm_head", policy=policy, rng=rng,
@@ -619,25 +647,21 @@ def prefill_chunk_step(
     }
     prog_blocks = pget(programmed, "blocks")
     for si, (seg_start, steps, tmpl) in enumerate(segments(cfg)):
-        seg_p = params["blocks"][f"seg{si}"]
-        seg_c = cache["blocks"][f"seg{si}"]
-        prog_seg = pget(prog_blocks, f"seg{si}")
         rng_s = jax.random.fold_in(rng, si)
 
-        def step(x, inp):
-            p_l, prog_l, c_l, idx = inp
-            rng_l = jax.random.fold_in(rng_s, idx)
-            x, st = block_chunk(
-                p_l, x, cfg, tmpl, policy=policy, rng=rng_l, state=c_l,
+        def layer(x, p_l, prog_l, state, idx):
+            return block_chunk(
+                p_l, x, cfg, tmpl, policy=policy,
+                rng=jax.random.fold_in(rng_s, idx), state=state, layer=idx,
                 bt_row=bt_row, start=start, n_valid=n_valid,
                 positions=positions, prepared=prog_l,
             )
-            return x, st
 
-        x, new_states = lax.scan(
-            step, x, (seg_p, prog_seg, seg_c, jnp.arange(steps))
+        x, new_cache["blocks"][f"seg{si}"] = _scan_layers(
+            layer, x, params["blocks"][f"seg{si}"],
+            pget(prog_blocks, f"seg{si}"), cache["blocks"][f"seg{si}"],
+            carried=True,
         )
-        new_cache["blocks"][f"seg{si}"] = new_states
     last = lax.dynamic_index_in_dim(
         x, n_valid - 1, axis=1, keepdims=False
     )  # (1, d) pre-norm hidden of the chunk's last real token

@@ -207,7 +207,7 @@ def _freeze_inactive(active, new, old):
 
 
 def _layer_decode(p, x1, cfg, layer_idx, *, policy, rng, pos, state,
-                  prepared=None, active=None, block_tables=None):
+                  prepared=None, active=None, block_tables=None, layer=None):
     kind, _ = cfg.layer_kind(layer_idx)
     name = f"L.{kind}"
     h = norm(x1, p["norm1"], cfg.norm)
@@ -217,7 +217,7 @@ def _layer_decode(p, x1, cfg, layer_idx, *, policy, rng, pos, state,
             p["attn"], h, cfg, policy=policy, rng=rng,
             cache_k=state["k"], cache_v=state["v"], pos=pos, name=name,
             prepared=pget(prepared, "attn"), active=active,
-            block_tables=block_tables,
+            block_tables=block_tables, layer=layer,
         )
         new_state["k"], new_state["v"] = ck, cv
     elif cfg.ssm.kind == "rwkv6":
@@ -246,13 +246,17 @@ def _layer_decode(p, x1, cfg, layer_idx, *, policy, rng, pos, state,
 
 
 def block_decode(p, x1, cfg, template_idx, *, policy, rng, pos, state,
-                 prepared=None, active=None, block_tables=None):
+                 prepared=None, active=None, block_tables=None, layer=None):
+    """One scan step of decode.  Dense cache: ``state`` is this step's
+    slice of the segment cache.  Paged cache (``block_tables`` given,
+    homogeneous attention only): ``state`` is the segment's stacked
+    arena and ``layer`` this step's index in it."""
     g = group_size(cfg)
     if g == 1:
         return _layer_decode(
             p, x1, cfg, template_idx,
             policy=policy, rng=rng, pos=pos, state=state, prepared=prepared,
-            active=active, block_tables=block_tables,
+            active=active, block_tables=block_tables, layer=layer,
         )
     new_states = {}
     for j in range(g):
@@ -265,11 +269,12 @@ def block_decode(p, x1, cfg, template_idx, *, policy, rng, pos, state,
     return x1, new_states
 
 
-def block_chunk(p, x, cfg, template_idx, *, policy, rng, state, bt_row,
-                start, n_valid, positions, prepared=None):
+def block_chunk(p, x, cfg, template_idx, *, policy, rng, state, layer,
+                bt_row, start, n_valid, positions, prepared=None):
     """One scan step of CHUNKED PREFILL (DESIGN.md §7): run a prompt
     chunk ``x`` (1, C, d) through one attention layer, writing its K/V
-    into the paged pool at this slot's block table.
+    into layer ``layer`` of the segment's stacked arena ``state`` at
+    this slot's block table.
 
     Attention-only — recurrent layers cannot replay a right-padded chunk
     (the serving loop rejects those families at construction).  Uses the
@@ -286,8 +291,8 @@ def block_chunk(p, x, cfg, template_idx, *, policy, rng, state, bt_row,
     h = norm(x, p["norm1"], cfg.norm)
     y, pk, pv = chunk_attention_block(
         p["attn"], h, cfg, policy=policy, rng=rng,
-        pool_k=state["k"], pool_v=state["v"], bt_row=bt_row, start=start,
-        n_valid=n_valid, positions=positions, name=name,
+        pool_k=state["k"], pool_v=state["v"], layer=layer, bt_row=bt_row,
+        start=start, n_valid=n_valid, positions=positions, name=name,
         prepared=pget(prepared, "attn"),
     )
     x = x + y
@@ -299,11 +304,11 @@ def block_chunk(p, x, cfg, template_idx, *, policy, rng, state, bt_row,
 
 
 def block_verify(p, x, cfg, template_idx, *, policy, rng, pos, state,
-                 block_tables, prepared=None, active=None):
+                 layer, block_tables, prepared=None, active=None):
     """One scan step of SPECULATIVE VERIFY (DESIGN.md §7): run the
     C-token verify chunk ``x`` (B, C, d) — last emitted token + draft
-    proposals per slot — through one attention layer against the paged
-    pool.
+    proposals per slot — through one attention layer against layer
+    ``layer`` of the segment's stacked arena ``state``.
 
     Attention-only, like chunked prefill (rejected drafts cannot be
     rolled back out of a recurrent carry; the serving loop rejects
@@ -322,8 +327,9 @@ def block_verify(p, x, cfg, template_idx, *, policy, rng, pos, state,
     h = norm(x, p["norm1"], cfg.norm)
     y, pk, pv = verify_attention_block(
         p["attn"], h, cfg, policy=policy, rng=rng,
-        pool_k=state["k"], pool_v=state["v"], block_tables=block_tables,
-        pos=pos, name=name, prepared=pget(prepared, "attn"), active=active,
+        pool_k=state["k"], pool_v=state["v"], layer=layer,
+        block_tables=block_tables, pos=pos, name=name,
+        prepared=pget(prepared, "attn"), active=active,
     )
     x = x + y
     h = norm(x, p["norm2"], cfg.norm)

@@ -10,6 +10,10 @@ Contract under test (DESIGN.md §3/§7):
   pool-pressure layouts where freed physical blocks are re-used by other
   slots.  (Chunk kernel: bitwise on the valid query rows; pad rows see a
   zero tail instead of gathered junk and are discarded by callers.)
+* **Layer index.**  The kernels read one layer of a stacked arena
+  ``(L, n_blocks, bs, KV*hd)`` whose layers hold different values: at
+  the first, a middle and the last layer they are bitwise the call on
+  a one-layer arena holding just that layer.
 * **Block-boundary writes.**  ``_paged_token_write`` at
   ``pos % block_size == 0`` lands the token in the freshly mapped block
   at offset 0 (regression: the first token of every new block), and
@@ -43,6 +47,8 @@ from repro.serve import Request, ServeConfig, ServeLoop, greedy_generate
 
 BS, NB, N_BLOCKS = 4, 8, 24  # S = 32 logical positions per slot
 KV, HD, H = 2, 16, 8
+LAYERS = 3  # a stacked arena: first, middle and last layer
+LAYER_IDS = [0, LAYERS // 2, LAYERS - 1]
 
 
 @pytest.fixture(autouse=True)
@@ -62,11 +68,17 @@ def _release_compile_cache():
 
 
 def _pools(seed=0, dtype=jnp.bfloat16):
+    """A stacked K and V arena; every layer holds different values."""
     k1, k2 = jax.random.split(jax.random.PRNGKey(seed))
     mk = lambda k: jax.random.normal(
-        k, (N_BLOCKS, BS, KV, HD), jnp.float32
+        k, (LAYERS, N_BLOCKS, BS, KV * HD), jnp.float32
     ).astype(dtype)
     return mk(k1), mk(k2)
+
+
+def _one_layer(pool, layer):
+    """The arena of that layer alone — what a single-layer call reads."""
+    return pool[layer:layer + 1]
 
 
 # block layouts: (block_tables, pos) pairs covering identity, permuted,
@@ -101,40 +113,59 @@ def _layouts():
     ]
 
 
+@pytest.mark.parametrize("layer", LAYER_IDS)
 @pytest.mark.parametrize("name,bt,pos", _layouts(), ids=[l[0] for l in _layouts()])
 @pytest.mark.parametrize("window", [0, 6])
-def test_decode_kernel_bitwise(name, bt, pos, window):
+def test_decode_kernel_bitwise(name, bt, pos, window, layer):
     pool_k, pool_v = _pools()
     q = jax.random.normal(jax.random.PRNGKey(7), (bt.shape[0], H, HD), jnp.float32)
     ref = attention_decode(
-        q, _paged_gather(pool_k, bt), _paged_gather(pool_v, bt), pos,
-        window=window,
+        q, _paged_gather(pool_k, layer, bt, HD),
+        _paged_gather(pool_v, layer, bt, HD), pos, window=window,
     )
     out = paged_decode_attention(
-        q, pool_k, pool_v, bt, pos, window=window, interpret=True
+        q, pool_k, pool_v, jnp.int32(layer), bt, pos, window=window,
+        interpret=True,
+    )
+    one = paged_decode_attention(
+        q, _one_layer(pool_k, layer), _one_layer(pool_v, layer),
+        jnp.int32(0), bt, pos, window=window, interpret=True,
     )
     assert out.dtype == ref.dtype
     assert bool(jnp.array_equal(
         out.astype(jnp.float32), ref.astype(jnp.float32)
-    )), f"{name} window={window}"
+    )), f"{name} window={window} layer={layer}"
+    assert bool(jnp.array_equal(
+        out.astype(jnp.float32), one.astype(jnp.float32)
+    )), f"{name} window={window} layer={layer}"
 
 
+@pytest.mark.parametrize("layer", LAYER_IDS)
 @pytest.mark.parametrize("name,bt,pos", _layouts(), ids=[l[0] for l in _layouts()])
-def test_decode_kernel_bitwise_f32_pool(name, bt, pos):
+def test_decode_kernel_bitwise_f32_pool(name, bt, pos, layer):
     pool_k, pool_v = _pools(dtype=jnp.float32)
     q = jax.random.normal(jax.random.PRNGKey(7), (bt.shape[0], H, HD), jnp.float32)
     ref = attention_decode(
-        q, _paged_gather(pool_k, bt), _paged_gather(pool_v, bt), pos
+        q, _paged_gather(pool_k, layer, bt, HD),
+        _paged_gather(pool_v, layer, bt, HD), pos,
     )
-    out = paged_decode_attention(q, pool_k, pool_v, bt, pos, interpret=True)
-    assert bool(jnp.array_equal(out, ref)), name
+    out = paged_decode_attention(
+        q, pool_k, pool_v, jnp.int32(layer), bt, pos, interpret=True
+    )
+    one = paged_decode_attention(
+        q, _one_layer(pool_k, layer), _one_layer(pool_v, layer),
+        jnp.int32(0), bt, pos, interpret=True,
+    )
+    assert bool(jnp.array_equal(out, ref)), f"{name} layer={layer}"
+    assert bool(jnp.array_equal(out, one)), f"{name} layer={layer}"
 
 
+@pytest.mark.parametrize("layer", LAYER_IDS)
 @pytest.mark.parametrize(
     "start,n_valid,window",
     [(0, 6, 0), (8, 6, 0), (8, 3, 0), (13, 6, 0), (26, 6, 0), (8, 6, 5)],
 )
-def test_chunk_kernel_bitwise_valid_rows(start, n_valid, window):
+def test_chunk_kernel_bitwise_valid_rows(start, n_valid, window, layer):
     """Chunk kernel == dense path on every VALID query row.  Pad rows
     (>= n_valid) attend over a zero tail instead of gathered junk — the
     caller discards them — but must stay finite."""
@@ -144,56 +175,66 @@ def test_chunk_kernel_bitwise_valid_rows(start, n_valid, window):
     q = jax.random.normal(jax.random.PRNGKey(11), (1, C, H, HD), jnp.float32)
     ref = attention_dense(
         q,
-        _paged_gather(pool_k, bt_row[None]),
-        _paged_gather(pool_v, bt_row[None]),
+        _paged_gather(pool_k, layer, bt_row[None], HD),
+        _paged_gather(pool_v, layer, bt_row[None], HD),
         q_off=start,
         window=window,
     )
     out = paged_chunk_attention(
-        q, pool_k, pool_v, bt_row, jnp.int32(start), jnp.int32(n_valid),
+        q, pool_k, pool_v, jnp.int32(layer), bt_row, jnp.int32(start),
+        jnp.int32(n_valid), window=window, interpret=True,
+    )
+    one = paged_chunk_attention(
+        q, _one_layer(pool_k, layer), _one_layer(pool_v, layer),
+        jnp.int32(0), bt_row, jnp.int32(start), jnp.int32(n_valid),
         window=window, interpret=True,
     )
     assert out.dtype == ref.dtype
     r = ref.astype(jnp.float32)[:, :n_valid]
     o = out.astype(jnp.float32)[:, :n_valid]
     assert bool(jnp.array_equal(o, r)), f"start={start} n_valid={n_valid}"
+    assert bool(jnp.array_equal(out, one)), f"layer={layer}"
     assert bool(jnp.isfinite(out.astype(jnp.float32)).all())
 
 
 def test_paged_token_write_block_boundary():
     """pos % block_size == 0 writes into offset 0 of the freshly mapped
-    block — and nothing else in the pool moves."""
-    pool = jnp.zeros((6, BS, KV, HD), jnp.float32)
+    block of the written layer — and nothing else in the arena moves."""
+    layer = 1
+    pool = jnp.zeros((LAYERS, 6, BS, KV * HD), jnp.float32)
     bt = jnp.array([[2, 3], [4, 5]], jnp.int32)
     val = jnp.ones((2, KV, HD), jnp.float32) * jnp.array(
         [[[1.0]], [[2.0]]]
     )
     pos = jnp.array([BS, 0], jnp.int32)  # slot 0: block 3 offset 0;
     active = jnp.array([True, True])     # slot 1: block 4 offset 0
-    new = _paged_token_write(pool, bt, pos, val, active)
-    g = _paged_gather(new, bt)
+    new = _paged_token_write(pool, layer, bt, pos, val, active)
+    g = _paged_gather(new, layer, bt, HD)
     assert bool(jnp.array_equal(g[0, BS], val[0]))
     assert bool(jnp.array_equal(g[1, 0], val[1]))
-    # exactly two pool rows were touched
-    changed = jnp.any(new != pool, axis=(1, 2, 3))
-    assert [int(i) for i in jnp.where(changed)[0]] == [3, 4]
+    # exactly two rows of the written layer were touched
+    changed = jnp.any(new != pool, axis=(2, 3))
+    assert [(int(l), int(b)) for l, b in zip(*jnp.where(changed))] == [
+        (layer, 3), (layer, 4)]
     # and the decode kernel sees the fresh block bitwise
     q = jax.random.normal(jax.random.PRNGKey(0), (2, H, HD), jnp.float32)
-    kpool = jnp.pad(new, ((0, 0), (0, 0), (0, 0), (0, 0)))
-    ref = attention_decode(q, _paged_gather(kpool, bt), _paged_gather(kpool, bt), pos)
-    out = paged_decode_attention(q, kpool, kpool, bt, pos, interpret=True)
+    ref = attention_decode(q, g, g, pos)
+    out = paged_decode_attention(q, new, new, jnp.int32(layer), bt, pos,
+                                 interpret=True)
     assert bool(jnp.array_equal(out, ref))
 
 
 def test_paged_token_write_inactive_routes_to_trash():
-    pool = jnp.zeros((6, BS, KV, HD), jnp.float32)
+    pool = jnp.zeros((LAYERS, 6, BS, KV * HD), jnp.float32)
     bt = jnp.array([[2, 3]], jnp.int32)
     val = jnp.ones((1, KV, HD), jnp.float32)
-    new = _paged_token_write(pool, bt, jnp.array([BS], jnp.int32), val,
+    new = _paged_token_write(pool, 2, bt, jnp.array([BS], jnp.int32), val,
                              jnp.array([False]))
     # the mapped block is untouched; the write landed in the trash block
-    assert bool(jnp.all(new[3] == 0))
-    assert bool(jnp.array_equal(new[TRASH_BLOCK, 0], val[0]))
+    # of the written layer, and no other layer moved
+    assert bool(jnp.all(new[2, 3] == 0))
+    assert bool(jnp.array_equal(new[2, TRASH_BLOCK, 0], val[0].reshape(-1)))
+    assert bool(jnp.all(new[:2] == 0))
 
 
 # ---------------------------------------------------------------------------
